@@ -75,6 +75,7 @@ import numpy as np
 from repro.configs.base import GRUConfig
 from repro.core import cells, gru, runtime
 from repro.core.params import init_params
+from repro.launch.compile_cache import enable_compile_cache
 
 # impl label -> executor backend preference. ALL exact names: each impl
 # pins one backend, so measurements are hermetic even when a stale
@@ -390,6 +391,7 @@ def _summarize(rows, depths, batches, hiddens):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="reduced sweep for CI (still emits the artifacts)")

@@ -23,6 +23,7 @@ from repro.configs.base import GRUConfig
 from repro.core import gru
 from repro.core.latency import gru_step_model
 from repro.core.params import init_params
+from repro.launch.compile_cache import enable_compile_cache
 
 HIDDEN = (20, 24, 28, 32)
 INPUTS = (5, 8, 32, 128, 256)
@@ -67,4 +68,5 @@ def run(csv=True, iters: int = 300):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

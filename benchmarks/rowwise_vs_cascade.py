@@ -31,17 +31,19 @@ from repro.configs.base import GRUConfig
 from repro.core import gru, runtime
 from repro.core.latency import gru_step_model
 from repro.core.params import init_params
+from repro.launch.compile_cache import enable_compile_cache
 
 _SUB = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
 from repro.configs.base import GRUConfig
+from repro.compat import make_mesh
 from repro.core import gru, rowparallel
 from repro.core.params import init_params
 from repro.launch.hloparse import analyze
 H, X, B, T = 64, 16, 1, 8
-mesh = jax.make_mesh((4,), ("model",))
+mesh = make_mesh((4,), ("model",))
 params = init_params(gru.gru_cell_specs(X, H), jax.random.key(0))
 h0 = jnp.zeros((B, H)); xs = jnp.ones((B, T, X))
 for mode in ("rowwise", "cascade"):
@@ -122,23 +124,24 @@ def run(csv=True):
         rows.append((f"e4_model_shards{shards}", 0.0,
                      f"v5e_step_ns={m.total_s*1e9:.1f};"
                      f"coll_ns={m.collective_s*1e9:.1f}"))
-    # (c) compiled collective study
+    # (c) compiled collective study: a host-mesh HLO study in a child with
+    # four virtual CPU devices. The child is held to the CPU: this process
+    # already holds the accelerator, if there is one.
     env = dict(os.environ)
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
-    try:
-        out = subprocess.run([sys.executable, "-c", _SUB], env=env, text=True,
-                             capture_output=True, timeout=420)
-        for line in out.stdout.splitlines():
-            if line.startswith("E4SUB,"):
-                _, name, cbytes, kinds = line.split(",", 3)
-                rows.append((f"e4_coll_{name}", 0.0,
-                             f"coll_bytes={cbytes};{kinds}"))
-        if out.returncode != 0:
-            rows.append(("e4_coll_error", 0.0, out.stderr[-200:].replace("\n", " ")))
-    except subprocess.TimeoutExpired:
-        rows.append(("e4_coll_timeout", 0.0, "subprocess timeout"))
+    out = subprocess.run([sys.executable, "-c", _SUB], env=env, text=True,
+                         capture_output=True, timeout=420)
+    if out.returncode != 0:
+        raise RuntimeError("collective study child failed "
+                           f"(rc={out.returncode}):\n{out.stderr[-2000:]}")
+    for line in out.stdout.splitlines():
+        if line.startswith("E4SUB,"):
+            _, name, cbytes, kinds = line.split(",", 3)
+            rows.append((f"e4_coll_{name}", 0.0,
+                         f"coll_bytes={cbytes};{kinds}"))
     if csv:
         for name, us, derived in rows:
             print(f"{name},{us:.2f},{derived}")
@@ -146,6 +149,7 @@ def run(csv=True):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--num-layers", type=int, nargs="+", default=None,
                     help="run ONLY the depth sweep at these stack depths")

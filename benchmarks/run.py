@@ -9,6 +9,8 @@ import sys
 
 def main() -> None:
     from benchmarks import fig3_latency, rowwise_vs_cascade, table1_resources
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     fig3_latency.run(csv=True, iters=120)
     table1_resources.run(csv=True)

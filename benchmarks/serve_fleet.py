@@ -53,6 +53,7 @@ import numpy as np
 from repro.configs.base import GRUConfig, get_smoke_config
 from repro.core import runtime
 from repro.core.params import init_params
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api as mapi
 from repro.serve.engine import Request, bucket_len
 from repro.serve.fleet import (FaultEvent, FaultInjector, FleetConfig,
@@ -317,6 +318,7 @@ def run(n: int = 120, rate: float = 20.0, hidden: int = 32, layers: int = 2,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="reduced load for CI (still emits the artifact and "

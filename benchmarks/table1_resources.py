@@ -20,6 +20,7 @@ from repro.configs.base import GRUConfig
 from repro.core import gru
 from repro.core.latency import gru_tile_cost
 from repro.core.params import init_params
+from repro.launch.compile_cache import enable_compile_cache
 
 HIDDEN = (20, 24, 28, 32)
 
@@ -52,4 +53,5 @@ def run(csv=True):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

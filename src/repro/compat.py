@@ -1,46 +1,29 @@
-"""Compatibility layer for jax API drift.
+"""The one home of the jax mesh and shard_map surface the repo uses.
 
-The codebase targets the modern surface (``jax.shard_map(check_vma=...)``,
-``jax.make_mesh(axis_types=...)``, ``jax.sharding.AxisType``); older jax
-(0.4.x) still ships ``jax.experimental.shard_map.shard_map(check_rep=...,
-auto=...)`` and a mesh without axis types. Everything in the repo goes
-through these two helpers so both toolchains work unchanged.
+Targets the installed jax (0.9): ``jax.make_mesh(axis_types=...)`` with
+``jax.sharding.AxisType`` and ``jax.shard_map(check_vma=...,
+axis_names=...)``. Everything in the repo builds meshes and shard_maps
+through these two helpers, so the defaults below hold everywhere.
 """
 from __future__ import annotations
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-except ImportError:           # jax < 0.5: every mesh axis behaves as Auto
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types where the concept exists."""
+    """``jax.make_mesh`` with every axis Auto."""
     kw = {} if devices is None else {"devices": devices}
-    if AxisType is not None:
-        kw["axis_types"] = (AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(axis_shapes, axis_names, **kw)
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names), **kw)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False,
               axis_names=None):
-    """``jax.shard_map`` on new jax, ``jax.experimental.shard_map`` shim on
-    old. ``axis_names`` (manual axes; the rest stay auto) maps to old jax's
-    complementary ``auto`` set; ``check_vma`` maps to ``check_rep``."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return sm(f, **kw)
-    from jax.experimental.shard_map import shard_map as esm
-    # Old jax: partial-manual regions (auto= on a multi-axis mesh) crash
-    # XLA's SPMD partitioner (IsManualSubgroup check), so run fully manual
-    # instead: axes outside ``axis_names`` are simply unused by the body and
-    # the computation is replicated across them — identical numerics, no
-    # auto-sharding inside the region.
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    """``jax.shard_map`` with ``check_vma`` off by default. ``axis_names``
+    (manual axes; the rest stay auto) narrows the manual region."""
+    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+              check_vma=check_vma)
+    if axis_names is not None:
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kw)

@@ -262,11 +262,14 @@ def gru_sequence_xla(params: dict, h0: jax.Array, xs: jax.Array, *,
     their unpadded prompts, since GRU biases make zero *inputs*
     non-neutral.
     """
-    m_t = None if mask is None else jnp.moveaxis(mask, -1, 0)  # (T, B)
+    # an unmasked call gates with an all-live mask: masked and unmasked
+    # calls then trace one scan body, so live rows stay bitwise-equal
+    m_t = (jnp.ones((xs.shape[-2],) + xs.shape[:-2], bool) if mask is None
+           else jnp.moveaxis(mask, -1, 0))                # (T, B)
     step = functools.partial(gru_step, params, cfg=cfg)
 
     def gated(h, h2, mt):
-        return h2 if mt is None else jnp.where(mt[..., None], h2, h)
+        return jnp.where(mt[..., None], h2, h)
 
     if cfg.decoupled_wx:
         xp = input_projection(params, xs, cfg)           # (..., T, 3H) one GEMM
@@ -426,7 +429,7 @@ def gru_classify(params: dict, xs: jax.Array, *, cfg: GRUConfig) -> jax.Array:
     cells = stack_cell_params(params, cfg)
     h0s = stack_h0(cfg, B, xs.dtype)
     finals, _ = runtime.sequence(cells, h0s, xs, cfg=cfg)
-    return finals[-1] @ params["head"]["w"] + params["head"]["b"]
+    return runtime.readout(finals[-1], params["head"])
 
 
 def gru_decode_step(params: dict, h: jax.Array, x: jax.Array, *, cfg: GRUConfig) -> jax.Array:

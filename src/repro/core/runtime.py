@@ -105,8 +105,8 @@ Cost calibration: ``benchmarks/decode_latency.py --emit-costs`` writes
 ``BENCH_backend_costs.json``; :func:`load_cost_model` /
 :func:`set_cost_model` install it (or it is picked up automatically from
 ``$REPRO_GRU_COSTS`` / ``./BENCH_backend_costs.json``). A missing or
-corrupt file degrades to the static table — selection is then identical
-to the pre-CostModel executor.
+corrupt file, or one calibrated on another platform, degrades to the
+static table — selection is then identical to the pre-CostModel executor.
 
 Legacy surface: ``plan()`` (one-shot resolve) and the ``ExecPlan`` name
 are deprecated shims over ``compile()``/``GRUExecutable`` — same memoized
@@ -122,6 +122,7 @@ import os
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 
 from repro.configs.base import GRUConfig
 from repro.core import cells as cell_families
@@ -443,12 +444,20 @@ class CostModel:
     @classmethod
     def load(cls, path) -> "CostModel":
         """Tolerant load: a missing, unreadable, or schema-mismatched file
-        yields an EMPTY model (every lookup misses -> static fallback)."""
+        yields an EMPTY model (every lookup misses -> static fallback).
+        So does an artifact measured on another platform than the one
+        this process runs on (its ``"device"``, as the calibration
+        benchmark records ``jax.default_backend()``): CPU interpret-mode
+        timings must never steer dispatch on a TPU, nor the reverse."""
         try:
             with open(path) as f:
                 data = json.load(f)
             if data.get("bench") != "gru_backend_costs":
                 raise ValueError("not a gru_backend_costs artifact")
+            here = jax.default_backend()
+            if data.get("device") != here:
+                raise ValueError(f"calibrated on {data.get('device')!r}, "
+                                 f"this process runs on {here!r}")
             return cls.from_entries(data["entries"], source=str(path))
         except Exception as e:  # noqa: BLE001 - degrade, never break dispatch
             return cls({}, source=str(path),
@@ -928,6 +937,22 @@ def _select(op: str, cfg: GRUConfig, *, masked: bool, placement: Placement,
 
 _EXEC_CACHE: Dict[tuple, GRUExecutable] = {}
 
+# Every backend call traces under this matmul precision: the cell-family
+# configs are float32 end to end, and on a TPU an f32 matmul at default
+# precision takes one bfloat16 pass, in XLA and in a Pallas kernel alike
+# (on a TPU v5e that put served states 5e-3 to 2.4e-2 off a float32
+# reference; at "highest" the fused kernels matched it exactly). It
+# reaches every matmul a backend traces — the layer-0 input GEMM, the XLA
+# scan's matvecs, the shard bodies and the kernels' own dots. The CPU
+# computes float32 either way.
+MATMUL_PRECISION = "highest"
+
+
+def readout(h, head: dict):
+    """Classifier head of every cell-family model: h (..., H) -> logits,
+    at the same :data:`MATMUL_PRECISION` as the recurrence it reads."""
+    return jnp.matmul(h, head["w"], precision=MATMUL_PRECISION) + head["b"]
+
 
 def compile(cfg: GRUConfig, *, batch: Optional[int] = None,
             seq: Optional[int] = None, placement=None, mask: bool = False,
@@ -1000,9 +1025,10 @@ def compile(cfg: GRUConfig, *, batch: Optional[int] = None,
                      pl_ if spec.caps.supports_mesh else None,
                      want_stacked=spec.name == "pallas_fused",
                      want_quant=spec.name.endswith("_q8"))
-        return spec.sequence_fn(sp, tuple(h0s), xs, cfg=cfg,
-                                return_all=return_all, mask=mask,
-                                placement=pl_)
+        with jax.default_matmul_precision(MATMUL_PRECISION):
+            return spec.sequence_fn(sp, tuple(h0s), xs, cfg=cfg,
+                                    return_all=return_all, mask=mask,
+                                    placement=pl_)
 
     def run_prefill(params, h0s, xs, *, mask=None):
         return run_sequence(params, h0s, xs, mask=mask)[0]
@@ -1012,7 +1038,9 @@ def compile(cfg: GRUConfig, *, batch: Optional[int] = None,
                      pl_ if dec_spec.caps.supports_mesh else None,
                      want_stacked=dec_spec.name == "pallas_fused",
                      want_quant=dec_spec.name.endswith("_q8"))
-        return dec_spec.decode_fn(sp, tuple(hs), x, cfg=cfg, placement=pl_)
+        with jax.default_matmul_precision(MATMUL_PRECISION):
+            return dec_spec.decode_fn(sp, tuple(hs), x, cfg=cfg,
+                                      placement=pl_)
 
     relevant = ([seq_src] if mode in ("prefill", "sequence") else
                 [dec_src] if mode == "decode" else [seq_src, dec_src])

@@ -132,23 +132,19 @@ def _layer_sequence_xla(cell: dict, group: tuple, xs: jax.Array, *,
     xp_t = jnp.moveaxis(xp, -2, 0)                       # time-major (T,B,4H)
     c0, n0, m0, h0 = (leaf.astype(jnp.float32) for leaf in group)
 
-    if mask is None:
-        def step(carry, xp_step):
-            new = slstm_gate_math(*carry, xp_step, u, b)
-            return new, (new[3] if return_all else None)
-        xs_scan = xp_t
-    else:
-        mask_t = jnp.moveaxis(mask, -1, 0) != 0          # (T,B) bool
+    # an unmasked call gates with an all-live mask: masked and unmasked
+    # calls then trace one scan body, so live rows stay bitwise-equal
+    mask_t = (jnp.ones(xp_t.shape[:-1], bool) if mask is None
+              else jnp.moveaxis(mask, -1, 0) != 0)        # (T,B) bool
 
-        def step(carry, inp):
-            xp_step, keep = inp
-            new = slstm_gate_math(*carry, xp_step, u, b)
-            new = tuple(jnp.where(keep[:, None], a, old)
-                        for a, old in zip(new, carry))
-            return new, (new[3] if return_all else None)
-        xs_scan = (xp_t, mask_t)
+    def step(carry, inp):
+        xp_step, keep = inp
+        new = slstm_gate_math(*carry, xp_step, u, b)
+        new = tuple(jnp.where(keep[..., None], a, old)
+                    for a, old in zip(new, carry))
+        return new, (new[3] if return_all else None)
 
-    finals, hs = jax.lax.scan(step, (c0, n0, m0, h0), xs_scan)
+    finals, hs = jax.lax.scan(step, (c0, n0, m0, h0), (xp_t, mask_t))
     if return_all:
         return finals, jnp.moveaxis(hs, 0, -2)           # (B,T,H)
     return finals, None

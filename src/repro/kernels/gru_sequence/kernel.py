@@ -33,11 +33,13 @@ state carried in scratch makes them order-dependent). This is the paper's
 figure of merit (single-step latency) with the AIE weight-residency story
 intact on TPU.
 
-Both sequence kernels take an optional (T, B) length MASK, streamed
-through the grid one (1, B) slice per step next to the input projection:
-False steps freeze the hidden state (every layer's, for the stack) with an
-in-kernel select, so bucketed left-padded prefill runs the fused kernels
-— unmasked rows execute bit-identical arithmetic to unpadded prompts.
+Every sequence kernel streams a (T, B) length MASK (all-ones when the
+caller passes none) through the grid, one (1, B, 1) block per step next to
+the input projection (layout: ``repro.kernels.step_mask``): False steps
+freeze the hidden state (every layer's, for the stack) with an in-kernel
+select, so bucketed left-padded prefill runs the fused kernels — and since
+masked and unmasked calls are one kernel program, live rows execute
+bit-identical arithmetic to unpadded prompts.
 
 SHARD-SHAPED entry points (``gru_rowwise_shard_*`` / ``gru_cascade_shard_*``
 / ``gru_shard_matvec``) are the ``pallas_sharded`` backend's kernels: each
@@ -60,6 +62,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import pick_batch_block, step_mask, step_mask_spec
 
 
 def _dot(a, b):
@@ -85,7 +89,13 @@ def _gate_math(h, xp, u, b, variant: str):
     return (1.0 - z) * h + z * ht
 
 
-def _seq_kernel(h0_ref, xp_ref, u_ref, b_ref, o_ref, h_s, *, variant: str):
+def _seq_kernel(h0_ref, xp_ref, u_ref, b_ref, m_ref, o_ref, h_s, *,
+                variant: str):
+    """One time step: the (1, B, 1) mask block streams in next to the
+    step's input projection; False rows keep their previous hidden state.
+    Live rows run EXACTLY the arithmetic of an all-live call (``where``
+    selects, it does not perturb), so left-padded bucketed prompts stay
+    bitwise-identical to their unpadded originals."""
     t = pl.program_id(0)
 
     @pl.when(t == 0)
@@ -93,30 +103,10 @@ def _seq_kernel(h0_ref, xp_ref, u_ref, b_ref, o_ref, h_s, *, variant: str):
         h_s[...] = h0_ref[...].astype(jnp.float32)
 
     xp = xp_ref[...][0].astype(jnp.float32)               # (B, 3H) this step
+    keep = m_ref[0] != 0.0                                # (B, 1) this step
     h_new = _gate_math(h_s[...], xp, u_ref[...],
                        b_ref[...].astype(jnp.float32), variant)
-    h_s[...] = h_new
-    o_ref[...] = h_new[None].astype(o_ref.dtype)
-
-
-def _seq_kernel_masked(h0_ref, xp_ref, u_ref, b_ref, m_ref, o_ref, h_s, *,
-                       variant: str):
-    """Masked variant: the (1, B) mask slice streams in next to the step's
-    input projection; False rows keep their previous hidden state. Unmasked
-    rows run EXACTLY the unmasked arithmetic (``where`` selects, it does not
-    perturb), so left-padded bucketed prompts stay bitwise-identical to
-    their unpadded originals."""
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        h_s[...] = h0_ref[...].astype(jnp.float32)
-
-    xp = xp_ref[...][0].astype(jnp.float32)               # (B, 3H) this step
-    keep = m_ref[...][0] != 0.0                           # (B,) this step
-    h_new = _gate_math(h_s[...], xp, u_ref[...],
-                       b_ref[...].astype(jnp.float32), variant)
-    h_new = jnp.where(keep[:, None], h_new, h_s[...])     # freeze masked rows
+    h_new = jnp.where(keep, h_new, h_s[...])              # freeze masked rows
     h_s[...] = h_new
     o_ref[...] = h_new[None].astype(o_ref.dtype)
 
@@ -128,63 +118,36 @@ def gru_sequence_kernel(h0: jax.Array, x_proj: jax.Array, u: jax.Array,
     """h0: (B,H), x_proj: (T,B,3H) time-major precomputed Wx, u: (H,3H),
     b: (3H,) -> all hidden states (T,B,H).
 
-    ``mask`` (T,B) float (nonzero = live step), optional: streamed through
-    the grid one (1,B) slice per step; False steps freeze the hidden state
-    in-kernel, so bucketed (left-padded) prefill runs the SAME fused kernel
-    as unpadded prompts instead of falling back to the XLA scan."""
+    ``mask`` (T,B) float (nonzero = live step; None = all live): streamed
+    through the grid one step per block; False steps freeze the hidden
+    state in-kernel, so bucketed (left-padded) prefill runs the SAME fused
+    kernel as unpadded prompts instead of falling back to the XLA scan."""
     T, B, H3 = x_proj.shape
     H = H3 // 3
-    in_specs = [
-        pl.BlockSpec((B, H), lambda t: (0, 0)),        # h0: resident
-        pl.BlockSpec((1, B, 3 * H), lambda t: (t, 0, 0)),  # stream step t
-        pl.BlockSpec((H, 3 * H), lambda t: (0, 0)),    # U: fetched ONCE
-        pl.BlockSpec((1, 3 * H), lambda t: (0, 0)),
-    ]
-    args = [h0, x_proj, u, b[None, :]]
-    if mask is None:
-        kern = functools.partial(_seq_kernel, variant=variant)
-    else:
-        kern = functools.partial(_seq_kernel_masked, variant=variant)
-        in_specs.append(pl.BlockSpec((1, B), lambda t: (t, 0)))  # step's mask
-        args.append(mask.astype(jnp.float32))
     return pl.pallas_call(
-        kern,
+        functools.partial(_seq_kernel, variant=variant),
         grid=(T,),
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((B, H), lambda t: (0, 0)),        # h0: resident
+            pl.BlockSpec((1, B, 3 * H), lambda t: (t, 0, 0)),  # stream step t
+            pl.BlockSpec((H, 3 * H), lambda t: (0, 0)),    # U: fetched ONCE
+            pl.BlockSpec((1, 3 * H), lambda t: (0, 0)),
+            step_mask_spec(B),                             # step t's mask
+        ],
         out_specs=pl.BlockSpec((1, B, H), lambda t: (t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((T, B, H), h0.dtype),
         scratch_shapes=[pltpu.VMEM((B, H), jnp.float32)],  # carried hidden state
         interpret=interpret,
-    )(*args)
+    )(h0, x_proj, u, b[None, :], step_mask(mask, T, B))
 
 
 # ---------------------------------------------------------------------------
 # fused multi-layer stack
 # ---------------------------------------------------------------------------
 
-def _stack_kernel(h0_ref, xp_ref, u_ref, wd_ref, b_ref, o_ref, hT_ref, h_s, *,
-                  variant: str, num_layers: int):
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        h_s[...] = h0_ref[...].astype(jnp.float32)
-
-    b = b_ref[...].astype(jnp.float32)                    # (L, 3H)
-    xp = xp_ref[...][0].astype(jnp.float32)               # (B, 3H): layer 0 Wx
-    for l in range(num_layers):                           # static unroll
-        h_new = _gate_math(h_s[l], xp, u_ref[l], b[l:l + 1], variant)
-        h_s[l] = h_new
-        if l + 1 < num_layers:
-            # next layer's input projection, same timestep, never leaves VMEM
-            xp = _dot(h_new.astype(wd_ref.dtype), wd_ref[l]).astype(jnp.float32)
-    o_ref[...] = h_new[None].astype(o_ref.dtype)
-    hT_ref[...] = h_s[...].astype(hT_ref.dtype)
-
-
-def _stack_kernel_masked(h0_ref, xp_ref, u_ref, wd_ref, b_ref, m_ref, o_ref,
-                         hT_ref, h_s, *, variant: str, num_layers: int):
-    """Masked fused stack: ONE shared (1, B) mask slice per step freezes
+def _stack_kernel(h0_ref, xp_ref, u_ref, wd_ref, b_ref, m_ref, o_ref,
+                  hT_ref, h_s, *, variant: str, num_layers: int):
+    """Fused stack, one time step: ONE shared (1, B, 1) mask block freezes
     EVERY layer's state on False rows (exact — during frozen steps upper
     layers ignore their input). The next layer consumes the GATED output,
     matching the layer-by-layer masked semantics of the XLA path."""
@@ -196,12 +159,13 @@ def _stack_kernel_masked(h0_ref, xp_ref, u_ref, wd_ref, b_ref, m_ref, o_ref,
 
     b = b_ref[...].astype(jnp.float32)                    # (L, 3H)
     xp = xp_ref[...][0].astype(jnp.float32)               # (B, 3H): layer 0 Wx
-    keep = m_ref[...][0] != 0.0                           # (B,) this step
+    keep = m_ref[0] != 0.0                                # (B, 1) this step
     for l in range(num_layers):                           # static unroll
         h_new = _gate_math(h_s[l], xp, u_ref[l], b[l:l + 1], variant)
-        h_new = jnp.where(keep[:, None], h_new, h_s[l])   # freeze masked rows
+        h_new = jnp.where(keep, h_new, h_s[l])            # freeze masked rows
         h_s[l] = h_new
         if l + 1 < num_layers:
+            # next layer's input projection, same timestep, never leaves VMEM
             xp = _dot(h_new.astype(wd_ref.dtype), wd_ref[l]).astype(jnp.float32)
     o_ref[...] = h_new[None].astype(o_ref.dtype)
     hT_ref[...] = h_s[...].astype(hT_ref.dtype)
@@ -219,34 +183,25 @@ def gru_stack_sequence_kernel(h0: jax.Array, x_proj: jax.Array, u: jax.Array,
     L=1, unused); b: (L,3H). Returns (last-layer states (T,B,H),
     per-layer final states (L,B,H)).
 
-    ``mask`` (T,B) float (nonzero = live step), optional: streamed one
-    (1,B) slice per grid step; False steps freeze every layer's hidden
-    state in-kernel (bucketed prefill runs the fused kernel, no XLA
-    fallback).
+    ``mask`` (T,B) float (nonzero = live step; None = all live): streamed
+    one step per grid block; False steps freeze every layer's hidden state
+    in-kernel (bucketed prefill runs the fused kernel, no XLA fallback).
     """
     T, B, H3 = x_proj.shape
     H = H3 // 3
     L = h0.shape[0]
     Ld = max(L - 1, 1)
-    in_specs = [
-        pl.BlockSpec((L, B, H), lambda t: (0, 0, 0)),      # h0: resident
-        pl.BlockSpec((1, B, 3 * H), lambda t: (t, 0, 0)),  # stream step t
-        pl.BlockSpec((L, H, 3 * H), lambda t: (0, 0, 0)),  # all U: ONCE
-        pl.BlockSpec((Ld,) + w_deep.shape[1:], lambda t: (0, 0, 0)),
-        pl.BlockSpec((L, 3 * H), lambda t: (0, 0)),
-    ]
-    args = [h0, x_proj, u, w_deep, b]
-    if mask is None:
-        kern = functools.partial(_stack_kernel, variant=variant, num_layers=L)
-    else:
-        kern = functools.partial(_stack_kernel_masked, variant=variant,
-                                 num_layers=L)
-        in_specs.append(pl.BlockSpec((1, B), lambda t: (t, 0)))  # step's mask
-        args.append(mask.astype(jnp.float32))
     hs, hT = pl.pallas_call(
-        kern,
+        functools.partial(_stack_kernel, variant=variant, num_layers=L),
         grid=(T,),
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((L, B, H), lambda t: (0, 0, 0)),      # h0: resident
+            pl.BlockSpec((1, B, 3 * H), lambda t: (t, 0, 0)),  # stream step t
+            pl.BlockSpec((L, H, 3 * H), lambda t: (0, 0, 0)),  # all U: ONCE
+            pl.BlockSpec((Ld,) + w_deep.shape[1:], lambda t: (0, 0, 0)),
+            pl.BlockSpec((L, 3 * H), lambda t: (0, 0)),
+            step_mask_spec(B),                                 # step t's mask
+        ],
         out_specs=[
             pl.BlockSpec((1, B, H), lambda t: (t, 0, 0)),
             pl.BlockSpec((L, B, H), lambda t: (0, 0, 0)),
@@ -255,7 +210,7 @@ def gru_stack_sequence_kernel(h0: jax.Array, x_proj: jax.Array, u: jax.Array,
                    jax.ShapeDtypeStruct((L, B, H), h0.dtype)],
         scratch_shapes=[pltpu.VMEM((L, B, H), jnp.float32)],  # per-layer h
         interpret=interpret,
-    )(*args)
+    )(h0, x_proj, u, w_deep, b, step_mask(mask, T, B))
     return hs, hT
 
 
@@ -278,14 +233,6 @@ def _decode_kernel(h_ref, xp_ref, u_ref, wd_ref, b_ref, o_ref, *,
             xp = _dot(h_new.astype(wd_ref.dtype), wd_ref[l]).astype(jnp.float32)
 
 
-def _pick_batch_block(B: int, limit: int = 256) -> int:
-    """Largest divisor of B that fits the VMEM budget heuristic."""
-    blk = min(B, limit)
-    while B % blk:
-        blk -= 1
-    return blk
-
-
 @functools.partial(jax.jit, static_argnames=("variant", "batch_block",
                                              "interpret"))
 def gru_stack_decode_kernel(h: jax.Array, x_proj: jax.Array, u: jax.Array,
@@ -306,13 +253,13 @@ def gru_stack_decode_kernel(h: jax.Array, x_proj: jax.Array, u: jax.Array,
     waves may run tiles on both TPU cores per chip).
     """
     L, B, H = h.shape
-    Bt = batch_block or _pick_batch_block(B)
+    Bt = batch_block or pick_batch_block(B)
     assert B % Bt == 0, (B, Bt)
     Ld = max(L - 1, 1)
     return pl.pallas_call(
         functools.partial(_decode_kernel, variant=variant, num_layers=L),
         grid=(B // Bt,),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         in_specs=[
             pl.BlockSpec((L, Bt, H), lambda i: (0, i, 0)),     # this batch tile
@@ -380,24 +327,9 @@ def _gate_math_q8(h, xp, uq, eff, b, variant: str):
     return (1.0 - z) * h + z * ht
 
 
-def _seq_kernel_q8(h0_ref, xp_ref, uq_ref, eff_ref, b_ref, o_ref, h_s, *,
-                   variant: str):
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        h_s[...] = h0_ref[...].astype(jnp.float32)
-
-    xp = xp_ref[...][0].astype(jnp.float32)               # (B, 3H) this step
-    h_new = _gate_math_q8(h_s[...], xp, uq_ref[...], eff_ref[...],
-                          b_ref[...].astype(jnp.float32), variant)
-    h_s[...] = h_new
-    o_ref[...] = h_new[None].astype(o_ref.dtype)
-
-
-def _seq_kernel_q8_masked(h0_ref, xp_ref, uq_ref, eff_ref, b_ref, m_ref,
-                          o_ref, h_s, *, variant: str):
-    """Masked q8 sequence: identical freeze semantics to the f32 kernel
+def _seq_kernel_q8(h0_ref, xp_ref, uq_ref, eff_ref, b_ref, m_ref, o_ref,
+                   h_s, *, variant: str):
+    """q8 sequence step: identical freeze semantics to the f32 kernel
     (``where`` selects, it does not perturb — and the quantized arithmetic
     of live rows is independent of dead rows), so bucketed left-padded
     prompts stay bitwise-identical to their unpadded q8 originals."""
@@ -408,10 +340,10 @@ def _seq_kernel_q8_masked(h0_ref, xp_ref, uq_ref, eff_ref, b_ref, m_ref,
         h_s[...] = h0_ref[...].astype(jnp.float32)
 
     xp = xp_ref[...][0].astype(jnp.float32)               # (B, 3H) this step
-    keep = m_ref[...][0] != 0.0                           # (B,) this step
+    keep = m_ref[0] != 0.0                                # (B, 1) this step
     h_new = _gate_math_q8(h_s[...], xp, uq_ref[...], eff_ref[...],
                           b_ref[...].astype(jnp.float32), variant)
-    h_new = jnp.where(keep[:, None], h_new, h_s[...])     # freeze masked rows
+    h_new = jnp.where(keep, h_new, h_s[...])              # freeze masked rows
     h_s[...] = h_new
     o_ref[...] = h_new[None].astype(o_ref.dtype)
 
@@ -424,36 +356,30 @@ def gru_sequence_q8_kernel(h0: jax.Array, x_proj: jax.Array, u_q: jax.Array,
     """q8 twin of :func:`gru_sequence_kernel`. h0: (B,H), x_proj: (T,B,3H)
     f32 time-major Wx, u_q: (3H,H) int8 weight rows (pinned in VMEM at a
     quarter of the f32 footprint), u_eff: (3H,) f32 per-row dequant
-    scales, b: (3H,) -> all hidden states (T,B,H) f32."""
+    scales, b: (3H,), mask: (T,B) or None -> all hidden states (T,B,H)
+    f32."""
     T, B, H3 = x_proj.shape
     H = H3 // 3
-    in_specs = [
-        pl.BlockSpec((B, H), lambda t: (0, 0)),            # h0: resident
-        pl.BlockSpec((1, B, 3 * H), lambda t: (t, 0, 0)),  # stream step t
-        pl.BlockSpec((3 * H, H), lambda t: (0, 0)),        # int8 U: ONCE
-        pl.BlockSpec((1, 3 * H), lambda t: (0, 0)),
-        pl.BlockSpec((1, 3 * H), lambda t: (0, 0)),
-    ]
-    args = [h0, x_proj, u_q, u_eff[None, :], b[None, :]]
-    if mask is None:
-        kern = functools.partial(_seq_kernel_q8, variant=variant)
-    else:
-        kern = functools.partial(_seq_kernel_q8_masked, variant=variant)
-        in_specs.append(pl.BlockSpec((1, B), lambda t: (t, 0)))  # step's mask
-        args.append(mask.astype(jnp.float32))
     return pl.pallas_call(
-        kern,
+        functools.partial(_seq_kernel_q8, variant=variant),
         grid=(T,),
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((B, H), lambda t: (0, 0)),            # h0: resident
+            pl.BlockSpec((1, B, 3 * H), lambda t: (t, 0, 0)),  # stream step t
+            pl.BlockSpec((3 * H, H), lambda t: (0, 0)),        # int8 U: ONCE
+            pl.BlockSpec((1, 3 * H), lambda t: (0, 0)),
+            pl.BlockSpec((1, 3 * H), lambda t: (0, 0)),
+            step_mask_spec(B),                                 # step t's mask
+        ],
         out_specs=pl.BlockSpec((1, B, H), lambda t: (t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((T, B, H), h0.dtype),
         scratch_shapes=[pltpu.VMEM((B, H), jnp.float32)],
         interpret=interpret,
-    )(*args)
+    )(h0, x_proj, u_q, u_eff[None, :], b[None, :], step_mask(mask, T, B))
 
 
 def _stack_kernel_q8(h0_ref, xp_ref, uq_ref, eff_ref, wdq_ref, wde_ref,
-                     b_ref, o_ref, hT_ref, h_s, *, variant: str,
+                     b_ref, m_ref, o_ref, hT_ref, h_s, *, variant: str,
                      num_layers: int):
     t = pl.program_id(0)
 
@@ -464,37 +390,14 @@ def _stack_kernel_q8(h0_ref, xp_ref, uq_ref, eff_ref, wdq_ref, wde_ref,
     b = b_ref[...].astype(jnp.float32)                    # (L, 3H)
     eff = eff_ref[...]                                    # (L, 3H)
     xp = xp_ref[...][0].astype(jnp.float32)               # (B, 3H): layer 0 Wx
+    keep = m_ref[0] != 0.0                                # (B, 1) this step
     for l in range(num_layers):                           # static unroll
         h_new = _gate_math_q8(h_s[l], xp, uq_ref[l], eff[l:l + 1],
                               b[l:l + 1], variant)
+        h_new = jnp.where(keep, h_new, h_s[l])            # freeze masked rows
         h_s[l] = h_new
         if l + 1 < num_layers:
             # deep input projection: int8 rows too (h_new is in (-1,1))
-            xp = (_doti(_q8_act(h_new), wdq_ref[l]).astype(jnp.float32)
-                  * wde_ref[l][None])
-    o_ref[...] = h_new[None].astype(o_ref.dtype)
-    hT_ref[...] = h_s[...].astype(hT_ref.dtype)
-
-
-def _stack_kernel_q8_masked(h0_ref, xp_ref, uq_ref, eff_ref, wdq_ref,
-                            wde_ref, b_ref, m_ref, o_ref, hT_ref, h_s, *,
-                            variant: str, num_layers: int):
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        h_s[...] = h0_ref[...].astype(jnp.float32)
-
-    b = b_ref[...].astype(jnp.float32)                    # (L, 3H)
-    eff = eff_ref[...]                                    # (L, 3H)
-    xp = xp_ref[...][0].astype(jnp.float32)               # (B, 3H): layer 0 Wx
-    keep = m_ref[...][0] != 0.0                           # (B,) this step
-    for l in range(num_layers):                           # static unroll
-        h_new = _gate_math_q8(h_s[l], xp, uq_ref[l], eff[l:l + 1],
-                              b[l:l + 1], variant)
-        h_new = jnp.where(keep[:, None], h_new, h_s[l])   # freeze masked rows
-        h_s[l] = h_new
-        if l + 1 < num_layers:
             xp = (_doti(_q8_act(h_new), wdq_ref[l]).astype(jnp.float32)
                   * wde_ref[l][None])
     o_ref[...] = h_new[None].astype(o_ref.dtype)
@@ -513,34 +416,26 @@ def gru_stack_sequence_q8_kernel(h0: jax.Array, x_proj: jax.Array,
     h0: (L,B,H); x_proj: (T,B,3H) f32 layer-0 Wx; u_q: (L,3H,H) int8
     weight rows with u_eff: (L,3H) dequant scales; wd_q: (L-1,3H,H) int8
     deep-layer input projections with wd_eff: (L-1,3H) (pass the
-    ``quantize_gru_cells`` placeholders for L=1, unused); b: (L,3H).
-    Returns (last-layer states (T,B,H), per-layer finals (L,B,H))."""
+    ``quantize_gru_cells`` placeholders for L=1, unused); b: (L,3H);
+    mask: (T,B) or None. Returns (last-layer states (T,B,H), per-layer
+    finals (L,B,H))."""
     T, B, H3 = x_proj.shape
     H = H3 // 3
     L = h0.shape[0]
     Ld = max(L - 1, 1)
-    in_specs = [
-        pl.BlockSpec((L, B, H), lambda t: (0, 0, 0)),      # h0: resident
-        pl.BlockSpec((1, B, 3 * H), lambda t: (t, 0, 0)),  # stream step t
-        pl.BlockSpec((L, 3 * H, H), lambda t: (0, 0, 0)),  # int8 U: ONCE
-        pl.BlockSpec((L, 3 * H), lambda t: (0, 0)),
-        pl.BlockSpec((Ld,) + wd_q.shape[1:], lambda t: (0, 0, 0)),
-        pl.BlockSpec((Ld, 3 * H), lambda t: (0, 0)),
-        pl.BlockSpec((L, 3 * H), lambda t: (0, 0)),
-    ]
-    args = [h0, x_proj, u_q, u_eff, wd_q, wd_eff, b]
-    if mask is None:
-        kern = functools.partial(_stack_kernel_q8, variant=variant,
-                                 num_layers=L)
-    else:
-        kern = functools.partial(_stack_kernel_q8_masked, variant=variant,
-                                 num_layers=L)
-        in_specs.append(pl.BlockSpec((1, B), lambda t: (t, 0)))  # step's mask
-        args.append(mask.astype(jnp.float32))
     hs, hT = pl.pallas_call(
-        kern,
+        functools.partial(_stack_kernel_q8, variant=variant, num_layers=L),
         grid=(T,),
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((L, B, H), lambda t: (0, 0, 0)),      # h0: resident
+            pl.BlockSpec((1, B, 3 * H), lambda t: (t, 0, 0)),  # stream step t
+            pl.BlockSpec((L, 3 * H, H), lambda t: (0, 0, 0)),  # int8 U: ONCE
+            pl.BlockSpec((L, 3 * H), lambda t: (0, 0)),
+            pl.BlockSpec((Ld,) + wd_q.shape[1:], lambda t: (0, 0, 0)),
+            pl.BlockSpec((Ld, 3 * H), lambda t: (0, 0)),
+            pl.BlockSpec((L, 3 * H), lambda t: (0, 0)),
+            step_mask_spec(B),                                 # step t's mask
+        ],
         out_specs=[
             pl.BlockSpec((1, B, H), lambda t: (t, 0, 0)),
             pl.BlockSpec((L, B, H), lambda t: (0, 0, 0)),
@@ -549,7 +444,7 @@ def gru_stack_sequence_q8_kernel(h0: jax.Array, x_proj: jax.Array,
                    jax.ShapeDtypeStruct((L, B, H), h0.dtype)],
         scratch_shapes=[pltpu.VMEM((L, B, H), jnp.float32)],
         interpret=interpret,
-    )(*args)
+    )(h0, x_proj, u_q, u_eff, wd_q, wd_eff, b, step_mask(mask, T, B))
     return hs, hT
 
 
@@ -584,13 +479,13 @@ def gru_stack_decode_q8_kernel(h: jax.Array, x_proj: jax.Array,
     the new per-layer states (L,B,H) f32 (the state itself stays f32: the
     convex update accumulates full precision; only the matvecs are int8)."""
     L, B, H = h.shape
-    Bt = batch_block or _pick_batch_block(B)
+    Bt = batch_block or pick_batch_block(B)
     assert B % Bt == 0, (B, Bt)
     Ld = max(L - 1, 1)
     return pl.pallas_call(
         functools.partial(_decode_kernel_q8, variant=variant, num_layers=L),
         grid=(B // Bt,),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         in_specs=[
             pl.BlockSpec((L, Bt, H), lambda i: (0, i, 0)),     # batch tile

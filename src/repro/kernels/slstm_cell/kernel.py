@@ -21,10 +21,11 @@ same fused structure advancing a whole batch through all L layers for ONE
 token, batch-tiled with ``dimension_semantics=("parallel",)`` (megacore
 may split independent tiles across cores), weights resident across tiles.
 
-Both sequence variants take an optional (T, B) mask streamed one (1, B)
-slice per step: False rows keep ALL FOUR state leaves (``where`` selects,
-it does not perturb), so bucketed left-padded prefill runs the fused
-kernel bitwise-identical to unpadded prompts.
+The sequence kernel streams a (T, B) mask (all-ones when the caller
+passes none) one (1, B, 1) block per step (``repro.kernels.step_mask``):
+False rows keep ALL FOUR state leaves (``where`` selects, it does not
+perturb), so bucketed left-padded prefill runs the fused kernel
+bitwise-identical to unpadded prompts.
 """
 from __future__ import annotations
 
@@ -34,6 +35,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import pick_batch_block, step_mask, step_mask_spec
 
 
 def _dot(a, b):
@@ -65,40 +68,12 @@ def _store(refs, l, leaves):
 
 
 def _stack_kernel(c0_ref, n0_ref, m0_ref, h0_ref, xp_ref, u_ref, wd_ref,
-                  b_ref, o_ref, cT_ref, nT_ref, mT_ref, hT_ref,
+                  b_ref, k_ref, o_ref, cT_ref, nT_ref, mT_ref, hT_ref,
                   c_s, n_s, m_s, h_s, *, num_layers: int):
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        c_s[...] = c0_ref[...].astype(jnp.float32)
-        n_s[...] = n0_ref[...].astype(jnp.float32)
-        m_s[...] = m0_ref[...].astype(jnp.float32)
-        h_s[...] = h0_ref[...].astype(jnp.float32)
-
-    b = b_ref[...].astype(jnp.float32)                    # (L, 4H)
-    xp = xp_ref[...][0].astype(jnp.float32)               # (B, 4H): layer-0 Wx
-    for l in range(num_layers):                           # static unroll
-        new = _gate_math(c_s[l], n_s[l], m_s[l], h_s[l], xp, u_ref[l],
-                         b[l:l + 1])
-        _store((c_s, n_s, m_s, h_s), l, new)
-        if l + 1 < num_layers:
-            # next layer's input projection, same timestep, stays in VMEM
-            xp = _dot(new[3].astype(wd_ref.dtype), wd_ref[l])
-    o_ref[...] = new[3][None].astype(o_ref.dtype)
-    cT_ref[...] = c_s[...].astype(cT_ref.dtype)
-    nT_ref[...] = n_s[...].astype(nT_ref.dtype)
-    mT_ref[...] = m_s[...].astype(mT_ref.dtype)
-    hT_ref[...] = h_s[...].astype(hT_ref.dtype)
-
-
-def _stack_kernel_masked(c0_ref, n0_ref, m0_ref, h0_ref, xp_ref, u_ref,
-                         wd_ref, b_ref, m_ref, o_ref, cT_ref, nT_ref, mT_ref,
-                         hT_ref, c_s, n_s, m_s, h_s, *, num_layers: int):
-    """Masked fused stack: ONE shared (1, B) mask slice per step freezes
+    """Fused stack, one time step: ONE shared (1, B, 1) mask block freezes
     every layer's FOUR state leaves on False rows (the stabilizer must
     freeze with the gates, or live steps after padding would see a wrong
-    log-scale max). Unmasked rows run exactly the unmasked arithmetic."""
+    log-scale max). Live rows run exactly the all-live arithmetic."""
     t = pl.program_id(0)
 
     @pl.when(t == 0)
@@ -110,14 +85,15 @@ def _stack_kernel_masked(c0_ref, n0_ref, m0_ref, h0_ref, xp_ref, u_ref,
 
     b = b_ref[...].astype(jnp.float32)                    # (L, 4H)
     xp = xp_ref[...][0].astype(jnp.float32)               # (B, 4H): layer-0 Wx
-    keep = m_ref[...][0] != 0.0                           # (B,) this step
+    keep = k_ref[0] != 0.0                                # (B, 1) this step
     for l in range(num_layers):                           # static unroll
         new = _gate_math(c_s[l], n_s[l], m_s[l], h_s[l], xp, u_ref[l],
                          b[l:l + 1])
-        new = tuple(jnp.where(keep[:, None], a, s[l])
+        new = tuple(jnp.where(keep, a, s[l])
                     for a, s in zip(new, (c_s, n_s, m_s, h_s)))
         _store((c_s, n_s, m_s, h_s), l, new)
         if l + 1 < num_layers:
+            # next layer's input projection, same timestep, stays in VMEM
             xp = _dot(new[3].astype(wd_ref.dtype), wd_ref[l])
     o_ref[...] = new[3][None].astype(o_ref.dtype)
     cT_ref[...] = c_s[...].astype(cT_ref.dtype)
@@ -139,8 +115,8 @@ def slstm_stack_sequence_kernel(c0: jax.Array, n0: jax.Array, m0: jax.Array,
     b: (L,4H). Returns (last-layer h states (T,B,H), then the four
     per-layer final leaves cT/nT/mT/hT, each (L,B,H)).
 
-    ``mask`` (T,B) float (nonzero = live step), optional: streamed one
-    (1,B) slice per grid step; False steps freeze every layer's c/n/m/h
+    ``mask`` (T,B) float (nonzero = live step; None = all live): streamed
+    one step per grid block; False steps freeze every layer's c/n/m/h
     in-kernel (bucketed prefill runs the fused kernel, no XLA fallback).
     """
     T, B, H4 = x_proj.shape
@@ -148,25 +124,18 @@ def slstm_stack_sequence_kernel(c0: jax.Array, n0: jax.Array, m0: jax.Array,
     L = h0.shape[0]
     Ld = max(L - 1, 1)
     state_spec = pl.BlockSpec((L, B, H), lambda t: (0, 0, 0))  # resident
-    in_specs = [
-        state_spec, state_spec, state_spec, state_spec,
-        pl.BlockSpec((1, B, 4 * H), lambda t: (t, 0, 0)),  # stream step t
-        pl.BlockSpec((L, H, 4 * H), lambda t: (0, 0, 0)),  # all U: ONCE
-        pl.BlockSpec((Ld,) + w_deep.shape[1:], lambda t: (0, 0, 0)),
-        pl.BlockSpec((L, 4 * H), lambda t: (0, 0)),
-    ]
-    args = [c0, n0, m0, h0, x_proj, u, w_deep, b]
-    if mask is None:
-        kern = functools.partial(_stack_kernel, num_layers=L)
-    else:
-        kern = functools.partial(_stack_kernel_masked, num_layers=L)
-        in_specs.append(pl.BlockSpec((1, B), lambda t: (t, 0)))  # step's mask
-        args.append(mask.astype(jnp.float32))
     fin = jax.ShapeDtypeStruct((L, B, H), h0.dtype)
     hs, cT, nT, mT, hT = pl.pallas_call(
-        kern,
+        functools.partial(_stack_kernel, num_layers=L),
         grid=(T,),
-        in_specs=in_specs,
+        in_specs=[
+            state_spec, state_spec, state_spec, state_spec,
+            pl.BlockSpec((1, B, 4 * H), lambda t: (t, 0, 0)),  # stream step t
+            pl.BlockSpec((L, H, 4 * H), lambda t: (0, 0, 0)),  # all U: ONCE
+            pl.BlockSpec((Ld,) + w_deep.shape[1:], lambda t: (0, 0, 0)),
+            pl.BlockSpec((L, 4 * H), lambda t: (0, 0)),
+            step_mask_spec(B),                                 # step t's mask
+        ],
         out_specs=[pl.BlockSpec((1, B, H), lambda t: (t, 0, 0))]
         + [pl.BlockSpec((L, B, H), lambda t: (0, 0, 0))] * 4,
         out_shape=[jax.ShapeDtypeStruct((T, B, H), h0.dtype),
@@ -174,7 +143,7 @@ def slstm_stack_sequence_kernel(c0: jax.Array, n0: jax.Array, m0: jax.Array,
         scratch_shapes=[pltpu.VMEM((L, B, H), jnp.float32)
                         for _ in range(4)],                # carried c/n/m/h
         interpret=interpret,
-    )(*args)
+    )(c0, n0, m0, h0, x_proj, u, w_deep, b, step_mask(mask, T, B))
     return hs, cT, nT, mT, hT
 
 
@@ -203,14 +172,6 @@ def _decode_kernel(c_ref, n_ref, m_ref, h_ref, xp_ref, u_ref, wd_ref, b_ref,
             xp = _dot(new[3].astype(wd_ref.dtype), wd_ref[l])
 
 
-def _pick_batch_block(B: int, limit: int = 256) -> int:
-    """Largest divisor of B that fits the VMEM budget heuristic."""
-    blk = min(B, limit)
-    while B % blk:
-        blk -= 1
-    return blk
-
-
 @functools.partial(jax.jit, static_argnames=("batch_block", "interpret"))
 def slstm_stack_decode_kernel(c: jax.Array, n: jax.Array, m: jax.Array,
                               h: jax.Array, x_proj: jax.Array, u: jax.Array,
@@ -228,7 +189,7 @@ def slstm_stack_decode_kernel(c: jax.Array, n: jax.Array, m: jax.Array,
     tiles carry no cross-tile state, so the axis is ``parallel``.
     """
     L, B, H = h.shape
-    Bt = batch_block or _pick_batch_block(B)
+    Bt = batch_block or pick_batch_block(B)
     assert B % Bt == 0, (B, Bt)
     Ld = max(L - 1, 1)
     tile = pl.BlockSpec((L, Bt, H), lambda i: (0, i, 0))
@@ -236,7 +197,7 @@ def slstm_stack_decode_kernel(c: jax.Array, n: jax.Array, m: jax.Array,
     return pl.pallas_call(
         functools.partial(_decode_kernel, num_layers=L),
         grid=(B // Bt,),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         in_specs=[
             tile, tile, tile, tile,                        # this batch tile

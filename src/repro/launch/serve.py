@@ -39,6 +39,7 @@ from repro.configs.base import get_config, get_smoke_config
 from repro.core import cells as cell_families
 from repro.core.params import init_params
 from repro.distributed.sharding import ShardCtx
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api as mapi
 from repro.serve.engine import Request, ServeEngine
 
@@ -102,6 +103,7 @@ def main(argv=None):
                         "prints the applied decisions (fleet mode tunes "
                         "each replica independently)")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     is_cell = cell_families.is_cell_family(cfg.family)

@@ -23,6 +23,7 @@ from repro.configs.base import ShapeConfig, TrainConfig, get_config, get_smoke_c
 from repro.data.pipeline import PipelineConfig, SyntheticStream
 from repro.distributed.fault_tolerance import StragglerMonitor
 from repro.distributed.sharding import ShardCtx
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import trainer
 
 
@@ -42,6 +43,7 @@ def main(argv=None):
     p.add_argument("--log-every", type=int, default=10)
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.family == "gru":

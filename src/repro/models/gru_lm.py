@@ -124,7 +124,7 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict, x: jax.Array, *,
                         placement=_placement(ctx))
     hs = p.decode(params, cache["h"], x)
     hs = tuple(constrain(h, ("batch", "act_gates"), ctx) for h in hs)
-    logits = hs[-1] @ params["head"]["w"] + params["head"]["b"]
+    logits = runtime.readout(hs[-1], params["head"])
     return logits.astype(jnp.float32), {"h": hs, "pos": cache["pos"] + 1}
 
 
@@ -145,8 +145,7 @@ def prefill(params: dict, cfg: ModelConfig, batch: dict, *,
                         mask=mask is not None, mode="prefill",
                         placement=_placement(ctx))
     finals = p.prefill(params, h0s, xs, mask=mask)
-    logits = (finals[-1] @ params["head"]["w"]
-              + params["head"]["b"]).astype(jnp.float32)
+    logits = runtime.readout(finals[-1], params["head"]).astype(jnp.float32)
     cache = {"h": tuple(h.astype(jnp.float32) for h in finals),
              "pos": jnp.array(xs.shape[1] - 1, jnp.int32)}
     return logits, cache

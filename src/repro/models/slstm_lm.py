@@ -52,7 +52,7 @@ def forward(params: dict, cfg: ModelConfig, batch: dict, *,
     cells = stack_cell_params(params, cfg.gru)
     state0 = slstm_core.stack_state0(cfg.gru, xs.shape[0], jnp.float32)
     finals, _ = runtime.sequence(cells, state0, xs, cfg=cfg.gru)
-    return finals[-1] @ params["head"]["w"] + params["head"]["b"]
+    return runtime.readout(finals[-1], params["head"])
 
 
 def loss_fn(params: dict, cfg: ModelConfig, batch: dict, *,
@@ -129,7 +129,7 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict, x: jax.Array, *,
                         placement=_placement(ctx))
     hs = p.decode(params, cache["h"], x)
     hs = tuple(constrain(h, ("batch", "act_gates"), ctx) for h in hs)
-    logits = hs[-1] @ params["head"]["w"] + params["head"]["b"]
+    logits = runtime.readout(hs[-1], params["head"])
     return logits.astype(jnp.float32), {"h": hs, "pos": cache["pos"] + 1}
 
 
@@ -149,8 +149,7 @@ def prefill(params: dict, cfg: ModelConfig, batch: dict, *,
                         mask=mask is not None, mode="prefill",
                         placement=_placement(ctx))
     finals = p.prefill(params, state0, xs, mask=mask)
-    logits = (finals[-1] @ params["head"]["w"]
-              + params["head"]["b"]).astype(jnp.float32)
+    logits = runtime.readout(finals[-1], params["head"]).astype(jnp.float32)
     cache = {"h": tuple(h.astype(jnp.float32) for h in finals),
              "pos": jnp.array(xs.shape[1] - 1, jnp.int32)}
     return logits, cache

@@ -237,6 +237,28 @@ def test_default_calibration_loads_from_env(tmp_path, monkeypatch):
     assert runtime.cost_model().source == str(path)
 
 
+@pytest.mark.parametrize("device", ["tpu", None])
+def test_calibration_from_another_platform_is_refused(tmp_path, monkeypatch,
+                                                      device):
+    """An artifact measured on another platform (or naming none) loads as
+    an empty model with ``error`` set, so the static table decides: CPU
+    interpret-mode timings never steer dispatch on the chip."""
+    path = tmp_path / "BENCH_backend_costs.json"
+    art = {"bench": "gru_backend_costs", "schema": 1,
+           "entries": _calib(3, 16, {"xla": 5.0, "pallas_fused": 50.0,
+                                     "pallas_chain": 60.0})}
+    if device is not None:
+        art["device"] = device
+    path.write_text(json.dumps(art))
+    m = runtime.CostModel.load(path)
+    assert len(m) == 0 and str(device) in m.error
+    monkeypatch.setenv("REPRO_GRU_COSTS", str(path))
+    runtime.set_cost_model(None)                 # re-arm the lazy load
+    exe = runtime.compile(_cfg(3), batch=1, mode="decode")
+    assert exe.cost_source == "static"
+    assert exe.decode_backend == "pallas_fused"
+
+
 def test_emit_costs_schema_loads():
     """benchmarks/decode_latency.py --emit-costs writes exactly what
     CostModel.load expects (schema lockstep, no benchmark run needed)."""

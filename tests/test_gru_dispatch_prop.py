@@ -15,10 +15,9 @@ backend pin vs auto, prefill vs decode — must always:
 * honor the bitwise mask contract wherever the executable CLAIMS
   ``mask_exact`` (padded+masked == unpadded at identical batch shapes).
 
-Runs under the optional-``hypothesis`` shim (``tests/_hyp.py``): with
-hypothesis installed (CI) the draws are derandomized — a fixed seed
-profile, so CI is deterministic; without it the property tests skip and
-the pinned ``test_dispatch_case_pinned`` corners still run.
+The hypothesis draws are derandomized — a fixed seed profile, so CI is
+deterministic; the pinned ``test_dispatch_case_pinned`` corners run
+alongside them.
 """
 import functools
 
@@ -27,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 from _q8 import q8_stack_decode, q8_stack_finals
 from repro.configs.base import GRUConfig
 from repro.core import cells, gru, runtime

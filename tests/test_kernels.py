@@ -94,3 +94,57 @@ def test_flash_attention_bf16():
     ref = fa_ref.attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
                                rtol=5e-2, atol=5e-2)
+
+
+def _masked_seq_case(family, depth):
+    """Raw-array inputs of one fused sequence kernel (depth-L stack, T=6,
+    B=3) -> (call(xp_t, mask_t), xp_t)."""
+    from repro.kernels.gru_sequence.kernel import gru_stack_sequence_kernel
+    from repro.kernels.slstm_cell.kernel import slstm_stack_sequence_kernel
+    G = 3 if family == "gru" else 4
+    L, B, H, T = depth, 3, 16, 6
+    ks = jax.random.split(jax.random.key(depth), 5)
+    xp = jax.random.normal(ks[0], (T, B, G * H))
+    u = jax.random.normal(ks[1], (L, H, G * H)) / np.sqrt(H)
+    wd = jax.random.normal(ks[2], (max(L - 1, 1), H, G * H)) / np.sqrt(H)
+    b = jax.random.normal(ks[3], (L, G * H)) * 0.1
+    if family == "gru":
+        h0 = jax.random.normal(ks[4], (L, B, H)) * 0.5
+        return (lambda x, m: gru_stack_sequence_kernel(
+            h0, x, u, wd, b, m, interpret=True)[1]), xp
+    z = jnp.zeros((L, B, H))
+    m0 = jnp.full((L, B, H), -1e30)
+    return (lambda x, m: jnp.stack(slstm_stack_sequence_kernel(
+        z, z, m0, z, x, u, wd, b, m, interpret=True)[1:])), xp
+
+
+@pytest.mark.parametrize("family", ["gru", "slstm"])
+@pytest.mark.parametrize("depth", [1, 3])
+def test_masked_sequence_kernel_layout_bitwise(family, depth):
+    """The (T, B, 1) mask stream: an unmasked call, an all-live mask and a
+    left-padded mask run one kernel program, so their final states are
+    BITWISE equal (the bucketed-prefill contract on the kernels' raw
+    interface)."""
+    call, xp = _masked_seq_case(family, depth)
+    T, B = xp.shape[:2]
+    unmasked = call(xp, None)
+    live = call(xp, jnp.ones((T, B)))
+    P = 3
+    padded = call(jnp.pad(xp, ((P, 0), (0, 0), (0, 0))),
+                  (jnp.arange(T + P) >= P)[:, None] * jnp.ones((1, B)))
+    np.testing.assert_array_equal(np.asarray(unmasked), np.asarray(live))
+    np.testing.assert_array_equal(np.asarray(unmasked), np.asarray(padded))
+
+
+def test_pick_batch_block_is_a_legal_tile():
+    """A decode tile is the whole batch or a multiple-of-8 divisor of it:
+    the only row counts the chip's kernel compiler accepts."""
+    from repro.kernels import pick_batch_block
+    for B in range(1, 1200):
+        blk = pick_batch_block(B)
+        assert B % blk == 0, (B, blk)
+        assert blk == B or blk % 8 == 0, (B, blk)
+        assert blk == B or blk <= 256, (B, blk)
+    assert pick_batch_block(300) == 300          # no multiple-of-8 divisor
+    assert pick_batch_block(512) == 256
+    assert pick_batch_block(8) == 8

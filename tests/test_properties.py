@@ -2,7 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs.base import TrainConfig
 from repro.models.layers import softmax_xent

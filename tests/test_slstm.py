@@ -301,10 +301,14 @@ def test_serve_engine_slstm_waves():
     assert stats["decode_backend_steps"], stats
     assert set(stats["decode_backend_steps"]) <= {"xla", "pallas_fused"}
     assert sum(stats["decode_backend_steps"].values()) == stats["steps"]
-    # decode-loop output equals the model API run on the same prompt
-    logits, _ = A.prefill(eng.params, cfg,
-                          {"features": jnp.asarray(reqs[0].prompt)[None]},
-                          ShardCtx())
+    # decode-loop output equals the model API run on the same prompt: the
+    # first class comes from one decode step on the last prompt feature
+    # (no stream given), as for the GRU engine
+    p = reqs[0].prompt
+    _, cache = A.prefill(eng.params, cfg, {"features": jnp.asarray(p)[None]},
+                         ShardCtx())
+    logits, _ = A.decode_step(eng.params, cfg, cache, jnp.asarray(p[-1][None]),
+                              ShardCtx())
     assert done[0].out[0] == int(jnp.argmax(logits, -1)[0])
 
 
