@@ -86,6 +86,7 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core import cells as cell_families
@@ -93,6 +94,22 @@ from repro.core.cells import UnknownCellFamily
 from repro.distributed.fault_tolerance import Clock, SystemClock
 from repro.distributed.sharding import ShardCtx
 from repro.models import api as mapi
+
+# Host spans of one ``gru_wave_step``, written into the profiler's trace
+# (no cost beyond an enter/exit while no trace is being taken). The step
+# span is the parent; the others are its children, in this order, and an
+# admit-free step has no admit, prefill or scatter span (docs/serving.md,
+# "Engine spans").
+SPAN_STEP = "engine.step"
+SPAN_ADMIT = "engine.admit"        # queue pop, slots, bucket, prompt staging
+SPAN_PREFILL = "engine.prefill"    # the prefill call (``prefill_times``)
+SPAN_SCATTER = "engine.scatter"    # admitted rows into the wave cache
+SPAN_FEED = "engine.feed"          # next-feature staging, per slot
+SPAN_DECODE = "engine.decode"      # the decode call (``step_times``)
+SPAN_READOUT = "engine.readout"    # argmax and class download
+SPAN_RETIRE = "engine.retire"      # classes out, lanes retired, drain check
+STEP_SPANS = (SPAN_ADMIT, SPAN_PREFILL, SPAN_SCATTER, SPAN_FEED,
+              SPAN_DECODE, SPAN_READOUT, SPAN_RETIRE)
 
 
 @dataclass
@@ -197,16 +214,17 @@ class ServeEngine:
         silently retrace; the key makes the compile-once contract checkable
         (see test_serve_engine_decode_cache_keyed_by_batch)."""
         if batch_shape not in self._decode_jit:
-            def fn(params, cache, tok):
+            def gru_decode(params, cache, tok):
                 return self.api.decode_step(params, self.cfg, cache, tok, self.ctx)
-            self._decode_jit[batch_shape] = jax.jit(fn, donate_argnums=(1,))
+            self._decode_jit[batch_shape] = jax.jit(gru_decode,
+                                                    donate_argnums=(1,))
         return self._decode_jit[batch_shape]
 
     def _get_prefill(self, S: int):
         if S not in self._prefill_jit:
-            def fn(params, batch):
+            def gru_prefill(params, batch):
                 return self.api.prefill(params, self.cfg, batch, self.ctx)
-            self._prefill_jit[S] = jax.jit(fn)
+            self._prefill_jit[S] = jax.jit(gru_prefill)
             if self._jit_gen > 0:
                 # a jit (re)created after a mid-serve retune: its first
                 # call recompiles, and that compile is a tuning cost —
@@ -222,11 +240,11 @@ class ServeEngine:
         cache into the ``k`` freed slots of the live wave cache
         (device-side, one trace per admit-batch size k <= max_batch)."""
         if k not in self._scatter_jit:
-            def fn(cache, fresh, slots_):
+            def gru_scatter(cache, fresh, slots_):
                 return {"h": tuple(h.at[slots_].set(f[:k]) for h, f in
                                    zip(cache["h"], fresh["h"])),
                         "pos": cache["pos"]}
-            self._scatter_jit[k] = jax.jit(fn)
+            self._scatter_jit[k] = jax.jit(gru_scatter)
         return self._scatter_jit[k]
 
     # -- LM waves -----------------------------------------------------------
@@ -357,10 +375,9 @@ class ServeEngine:
             return
         self.prefill_times.append(dt)
 
-    def _gru_prefill(self, prompts: List[np.ndarray]):
-        """One bucketed prefill of up to max_batch prompts; returns cache."""
-        Sb = self._bucket_for(max(p.shape[0] for p in prompts))
-        feats, mask = self._gru_prefill_batch(prompts, Sb)
+    def _gru_prefill(self, Sb: int, feats: np.ndarray, mask: np.ndarray):
+        """One bucketed prefill of a staged slot batch (bucket ``Sb``, from
+        ``_gru_prefill_batch``); returns cache."""
         backend = self._prefill_backend_for(Sb)
         if backend is not None:          # record the executor's choice
             self.prefill_backends.append(backend)
@@ -570,72 +587,88 @@ class ServeEngine:
         every empty slot (ALL admits share ONE bucketed prefill + one
         scatter), run one fused decode step over the fixed slots, retire
         finished lanes. Returns the requests that finished this step."""
-        w = self._wave
-        if w is None:
-            return []
-        X = self.cfg.gru.input_dim
-        empty = [j for j, s in enumerate(w.slots) if s is None]
-        if empty and w.pending:
-            k = min(len(empty), len(w.pending))
-            admits = [self._make_slot(w.pending.popleft()) for _ in range(k)]
-            now = self.clock.now()
-            for s in admits:
-                s.req.t_admit = now
-                if s.req.t_submit is not None:
-                    self.queue_waits.append(now - s.req.t_submit)
-            fresh = self._gru_prefill(
-                [np.asarray(s.req.prompt, np.float32).reshape(-1, X)
-                 for s in admits])
-            if w.cache is None:
-                # first cohort: the prefilled cache IS the wave cache (row
-                # i belongs to slot i; surplus rows are fully masked)
-                w.cache = fresh
-            else:
-                w.cache = self._get_scatter(k)(
-                    w.cache, fresh, jnp.asarray(empty[:k], jnp.int32))
-            for j, s in zip(empty[:k], admits):
-                w.slots[j] = s
-        if not any(s is not None for s in w.slots):
-            return []
-        for j, s in enumerate(w.slots):
-            if s is None:
-                w.nxt[j] = 0.0
-                continue
-            r = s.req
-            w.nxt[j] = (r.stream[s.step] if r.stream is not None
-                        and s.step < len(r.stream) else s.last_feat)
-        # attribution is frozen per decode-jit key AT TRACE TIME
-        # (_decode_backend_for): the jitted step embeds whichever backend
-        # the executor resolved when it first traced, and later cost-model
-        # epoch bumps do NOT retrace it — so a fresh compile() mid-wave
-        # could only MIS-attribute. Steps are recorded under the key they
-        # ran with; if admits ever change the decode key (live-batch
-        # resizing), the new key resolves its own backend on first use.
-        decode = self._get_decode(w.key)
-        t0 = self.clock.now()
-        logits, w.cache = decode(self.params, w.cache, jnp.asarray(w.nxt))
-        logits.block_until_ready()
-        self._record_step(w.key, self.clock.now() - t0,
-                          self._decode_backend_for(w.key))
-        cls = np.asarray(jnp.argmax(logits, -1))
-        finished = []
-        for j, s in enumerate(w.slots):
-            if s is None:
-                continue
-            r = s.req
-            r.out.append(int(cls[j]))
-            s.step += 1
-            if (int(cls[j]) == r.eos_id
-                    or len(r.out) >= r.max_new_tokens):
-                self._finish(r)
-                w.slots[j] = None                       # retire mid-wave
-                finished.append(r)
-        if not w.pending and all(s is None for s in w.slots):
-            # the wave just drained: a boundary. The tuner may retune now
-            # (possibly invalidating jits / resizing slots) — the next
-            # enqueue starts a fresh wave against the new configuration.
-            self._maybe_retune()
-        return finished
+        with TraceAnnotation(SPAN_STEP):
+            w = self._wave
+            if w is None:
+                return []
+            X = self.cfg.gru.input_dim
+            empty = [j for j, s in enumerate(w.slots) if s is None]
+            if empty and w.pending:
+                with TraceAnnotation(SPAN_ADMIT):
+                    k = min(len(empty), len(w.pending))
+                    admits = [self._make_slot(w.pending.popleft())
+                              for _ in range(k)]
+                    now = self.clock.now()
+                    for s in admits:
+                        s.req.t_admit = now
+                        if s.req.t_submit is not None:
+                            self.queue_waits.append(now - s.req.t_submit)
+                    prompts = [np.asarray(s.req.prompt,
+                                          np.float32).reshape(-1, X)
+                               for s in admits]
+                    Sb = self._bucket_for(max(p.shape[0] for p in prompts))
+                    feats, mask = self._gru_prefill_batch(prompts, Sb)
+                with TraceAnnotation(SPAN_PREFILL):
+                    fresh = self._gru_prefill(Sb, feats, mask)
+                with TraceAnnotation(SPAN_SCATTER):
+                    if w.cache is None:
+                        # first cohort: the prefilled cache IS the wave
+                        # cache (row i belongs to slot i; surplus rows are
+                        # fully masked)
+                        w.cache = fresh
+                    else:
+                        w.cache = self._get_scatter(k)(
+                            w.cache, fresh, jnp.asarray(empty[:k], jnp.int32))
+                    for j, s in zip(empty[:k], admits):
+                        w.slots[j] = s
+            if not any(s is not None for s in w.slots):
+                return []
+            with TraceAnnotation(SPAN_FEED):
+                for j, s in enumerate(w.slots):
+                    if s is None:
+                        w.nxt[j] = 0.0
+                        continue
+                    r = s.req
+                    w.nxt[j] = (r.stream[s.step] if r.stream is not None
+                                and s.step < len(r.stream) else s.last_feat)
+            # attribution is frozen per decode-jit key AT TRACE TIME
+            # (_decode_backend_for): the jitted step embeds whichever
+            # backend the executor resolved when it first traced, and later
+            # cost-model epoch bumps do NOT retrace it — so a fresh
+            # compile() mid-wave could only MIS-attribute. Steps are
+            # recorded under the key they ran with; if admits ever change
+            # the decode key (live-batch resizing), the new key resolves its
+            # own backend on first use.
+            with TraceAnnotation(SPAN_DECODE):
+                decode = self._get_decode(w.key)
+                t0 = self.clock.now()
+                logits, w.cache = decode(self.params, w.cache,
+                                         jnp.asarray(w.nxt))
+                logits.block_until_ready()
+                self._record_step(w.key, self.clock.now() - t0,
+                                  self._decode_backend_for(w.key))
+            with TraceAnnotation(SPAN_READOUT):
+                cls = np.asarray(jnp.argmax(logits, -1))
+            with TraceAnnotation(SPAN_RETIRE):
+                finished = []
+                for j, s in enumerate(w.slots):
+                    if s is None:
+                        continue
+                    r = s.req
+                    r.out.append(int(cls[j]))
+                    s.step += 1
+                    if (int(cls[j]) == r.eos_id
+                            or len(r.out) >= r.max_new_tokens):
+                        self._finish(r)
+                        w.slots[j] = None               # retire mid-wave
+                        finished.append(r)
+                if not w.pending and all(s is None for s in w.slots):
+                    # the wave just drained: a boundary. The tuner may
+                    # retune now (possibly invalidating jits / resizing
+                    # slots) — the next enqueue starts a fresh wave against
+                    # the new configuration.
+                    self._maybe_retune()
+            return finished
 
     # -- stats --------------------------------------------------------------
 
