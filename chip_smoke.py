@@ -76,17 +76,24 @@ def serve_wave(engine, reqs):
     """Serve one wave through the engine's stepwise wave API and return,
     per request, its final recurrent state: the row its slot held in the
     wave cache when it retired. (Every request decodes at least two steps,
-    so none retires in the step that admits it.)"""
+    so none retires in the step that admits it.) Each step waits on the
+    device once, for its classes."""
     finals = {}
     engine.gru_wave_begin(reqs)
     wave = engine._wave
+    waits, steps = engine.device_waits, 0
     while engine.gru_wave_active():
         lanes = {id(s.req): j for j, s in enumerate(wave.slots) if s}
-        for r in engine.gru_wave_step():
+        finished = engine.gru_wave_step()
+        steps += 1
+        for r in finished:
             j = lanes[id(r)]
             finals[id(r)] = [np.asarray(leaf[j]) for leaf in wave.cache["h"]]
     _check(all(r.done and len(r.out) == r.max_new_tokens for r in reqs),
            "a request did not finish with its full decode budget")
+    waits = engine.device_waits - waits
+    _check(waits == steps,
+           f"the wave waited on the device {waits} times in {steps} steps")
     return [finals[id(r)] for r in reqs]
 
 
@@ -201,7 +208,7 @@ def smoke_config(arch: str, waves=WAVES) -> dict:
               f"prefill={sorted(set(engine.prefill_backends))} "
               f"decode={engine.decode_backend} "
               f"max_abs_err={err:.3g} (tol {TOL:g}) wrong_classes={wrong} "
-              f"near_ties={ties}")
+              f"near_ties={ties} device_waits={engine.device_waits}")
         print(f"  {arch} wave {w} cost_source: {cost_sources(engine)}")
         check_result(err, wrong)
         if w == 0:

@@ -102,11 +102,11 @@ from repro.models import api as mapi
 # "Engine spans").
 SPAN_STEP = "engine.step"
 SPAN_ADMIT = "engine.admit"        # queue pop, slots, bucket, prompt staging
-SPAN_PREFILL = "engine.prefill"    # the prefill call (``prefill_times``)
+SPAN_PREFILL = "engine.prefill"    # the prefill dispatch (``prefill_times``)
 SPAN_SCATTER = "engine.scatter"    # admitted rows into the wave cache
 SPAN_FEED = "engine.feed"          # next-feature staging, per slot
-SPAN_DECODE = "engine.decode"      # the decode call (``step_times``)
-SPAN_READOUT = "engine.readout"    # argmax and class download
+SPAN_DECODE = "engine.decode"      # the decode dispatch
+SPAN_READOUT = "engine.readout"    # the step's one wait: class download
 SPAN_RETIRE = "engine.retire"      # classes out, lanes retired, drain check
 STEP_SPANS = (SPAN_ADMIT, SPAN_PREFILL, SPAN_SCATTER, SPAN_FEED,
               SPAN_DECODE, SPAN_READOUT, SPAN_RETIRE)
@@ -205,17 +205,24 @@ class ServeEngine:
                                                 # with step_times)
         self.queue_waits: List[float] = []      # per request: submit -> admit
         self.e2e_times: List[float] = []        # per request: submit -> finish
+        self.device_waits = 0                   # waits on the device by the
+                                                # wave path: one per decode step
 
     # -- jit caches ---------------------------------------------------------
 
     def _get_decode(self, batch_shape: tuple):
-        """Decode step jit, keyed by the new-input batch shape. The cache is
-        donated, so an unkeyed entry reused at a different batch shape would
-        silently retrace; the key makes the compile-once contract checkable
-        (see test_serve_engine_decode_cache_keyed_by_batch)."""
+        """Decode step jit, keyed by the new-input batch shape; returns
+        ``(classes, cache)``: the argmax of the step's logits (int32, first
+        index on ties) is computed inside the program, so the classes are
+        the only result the host downloads. The cache is donated, so an
+        unkeyed entry reused at a different batch shape would silently
+        retrace; the key makes the compile-once contract checkable (see
+        test_serve_engine_decode_cache_keyed_by_batch)."""
         if batch_shape not in self._decode_jit:
             def gru_decode(params, cache, tok):
-                return self.api.decode_step(params, self.cfg, cache, tok, self.ctx)
+                logits, cache = self.api.decode_step(params, self.cfg, cache,
+                                                     tok, self.ctx)
+                return jnp.argmax(logits, -1).astype(jnp.int32), cache
             self._decode_jit[batch_shape] = jax.jit(gru_decode,
                                                     donate_argnums=(1,))
         return self._decode_jit[batch_shape]
@@ -292,8 +299,8 @@ class ServeEngine:
         finished = np.zeros(B, bool)
         for _ in range(max_new):
             t0 = self.clock.now()
-            logits, cache = decode(self.params, cache, next_tok)
-            logits.block_until_ready()
+            decoded, cache = decode(self.params, cache, next_tok)
+            decoded.block_until_ready()
             self._record_step(key, self.clock.now() - t0)
             tok_np = np.asarray(next_tok)
             for i, r in enumerate(reqs):
@@ -305,7 +312,7 @@ class ServeEngine:
                         self._finish(r)
             if finished.all():
                 break
-            next_tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            next_tok = decoded
         for r in reqs:
             if not r.done:
                 self._finish(r)
@@ -377,15 +384,18 @@ class ServeEngine:
 
     def _gru_prefill(self, Sb: int, feats: np.ndarray, mask: np.ndarray):
         """One bucketed prefill of a staged slot batch (bucket ``Sb``, from
-        ``_gru_prefill_batch``); returns cache."""
+        ``_gru_prefill_batch``); returns cache. Nothing waits on the
+        device here: the host reads no prefill result, so the cache flows
+        on to the scatter and the decode, which the device runs in order.
+        ``prefill_times`` records what the host pays for the call: the
+        copies of features and mask and the dispatch."""
         backend = self._prefill_backend_for(Sb)
         if backend is not None:          # record the executor's choice
             self.prefill_backends.append(backend)
         prefill = self._get_prefill(Sb)
         t0 = self.clock.now()
-        logits, cache = prefill(self.params, {"features": jnp.asarray(feats),
-                                              "mask": jnp.asarray(mask)})
-        logits.block_until_ready()
+        _, cache = prefill(self.params, {"features": jnp.asarray(feats),
+                                         "mask": jnp.asarray(mask)})
         self._record_prefill(Sb, self.clock.now() - t0)
         return cache
 
@@ -586,7 +596,11 @@ class ServeEngine:
         """Advance the wave ONE decode step: admit queued requests into
         every empty slot (ALL admits share ONE bucketed prefill + one
         scatter), run one fused decode step over the fixed slots, retire
-        finished lanes. Returns the requests that finished this step."""
+        finished lanes. Returns the requests that finished this step.
+
+        The prefill, the scatter and the decode are dispatched back to
+        back; the step waits on the device once, for the classes
+        (``device_waits``)."""
         with TraceAnnotation(SPAN_STEP):
             w = self._wave
             if w is None:
@@ -638,17 +652,19 @@ class ServeEngine:
             # compile() mid-wave could only MIS-attribute. Steps are
             # recorded under the key they ran with; if admits ever change
             # the decode key (live-batch resizing), the new key resolves its
-            # own backend on first use.
+            # own backend on first use. A step's time runs from the decode
+            # dispatch to the classes in hand, so on an admitting step it
+            # also holds the device time of this step's prefill and scatter.
             with TraceAnnotation(SPAN_DECODE):
                 decode = self._get_decode(w.key)
                 t0 = self.clock.now()
-                logits, w.cache = decode(self.params, w.cache,
-                                         jnp.asarray(w.nxt))
-                logits.block_until_ready()
+                classes, w.cache = decode(self.params, w.cache,
+                                          jnp.asarray(w.nxt))
+            with TraceAnnotation(SPAN_READOUT):
+                cls = np.asarray(classes)
+                self.device_waits += 1
                 self._record_step(w.key, self.clock.now() - t0,
                                   self._decode_backend_for(w.key))
-            with TraceAnnotation(SPAN_READOUT):
-                cls = np.asarray(jnp.argmax(logits, -1))
             with TraceAnnotation(SPAN_RETIRE):
                 finished = []
                 for j, s in enumerate(w.slots):
@@ -724,7 +740,8 @@ class ServeEngine:
         the prefill story). Empty histories report NaN, never 0.0 — an
         engine that served nothing has no percentiles (``steps`` /
         ``requests`` / ``prefills`` say how much history backs each
-        number)."""
+        number). ``device_waits`` counts the wave path's waits on the
+        device: one per decode step."""
         ts = self.step_times
         pf = self.prefill_times
         qw = self.queue_waits
@@ -768,4 +785,5 @@ class ServeEngine:
                 "steps": len(ts),
                 "prefill_mean_s": _mean(pf),
                 "prefill_p99_s": _pct(pf, 99),
-                "prefills": len(self.prefill_times)}
+                "prefills": len(self.prefill_times),
+                "device_waits": self.device_waits}

@@ -14,8 +14,8 @@ from repro.core.params import init_params
 from repro.distributed.sharding import ShardCtx
 from repro.models import api as mapi
 from repro.serve import engine as engine_mod
-from repro.serve.engine import (SPAN_STEP, STEP_SPANS, Request,
-                                ServeEngine)
+from repro.serve.engine import (SPAN_DECODE, SPAN_READOUT, SPAN_STEP,
+                                STEP_SPANS, Request, ServeEngine)
 
 ADMIT_FREE = STEP_SPANS[3:]         # feed, decode, readout, retire
 BUDGETS = [2, 5, 3, 4, 1, 2, 3]
@@ -131,8 +131,14 @@ def test_phases_cover_the_step(runs):
 @pytest.mark.parametrize("span,record", [("engine.decode", "step_times"),
                                          ("engine.prefill", "prefill_times")])
 def test_call_spans_agree_with_the_engine_records(runs, span, record):
+    """A ``prefill_times`` record is its ``engine.prefill`` span. A
+    ``step_times`` record runs from the decode dispatch to the classes in
+    hand, so it is the step's ``engine.decode`` and ``engine.readout``."""
     r = runs("traced")
-    got = [(e - s) * 1e-9 for n, s, e in r["spans"] if n == span]
+    parts = (span, SPAN_READOUT) if span == SPAN_DECODE else (span,)
+    got = [sum(e - s for n, s, e in children if n in parts) * 1e-9
+           for _, children in _steps(r["spans"])
+           if any(n == span for n, _, _ in children)]
     want = r["records"][record]
     calls = (len(r["admitted"]) if span == "engine.decode"
              else sum(r["admitted"]))
