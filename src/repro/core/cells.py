@@ -63,6 +63,9 @@ class CellFamily:
     ``cell_specs(input_dim, hidden_dim)`` / ``stack_specs(cfg)``: parameter
     pytree specs (:class:`repro.core.params.Spec`).
     ``init_state(cfg, batch, dtype)``: the flat initial-state tuple.
+    ``state_dtype``: the dtype the classifier's state is carried in, or
+    None for the features' dtype (sLSTM: float32, so the normalizer and
+    the stabilizer never drop to a narrower feature dtype).
     ``normalize(params, cfg)``: any accepted param layout -> per-layer
     ``({"w","u","b"}, ...)`` cells tuple.
     ``reference(cells, state0, xs, *, return_all, mask)``: the dense fp32
@@ -88,6 +91,7 @@ class CellFamily:
                                                           default=None)
     supports_quant: bool = False
     supports_placement: bool = False
+    state_dtype: Optional[str] = None
 
     def state0(self, cfg, batch: int, dtype=None):
         """Flat initial-state tuple for a depth-L stack (layer-major)."""

@@ -135,19 +135,25 @@ def stack_cell_params(params, cfg: Optional[GRUConfig] = None) -> tuple:
 
 
 def gru_classifier_specs(cfg: GRUConfig) -> dict:
-    """The paper's jet-tagging model: GRU stack + linear classifier head.
+    """The paper's jet-tagging model: the config's cell-family stack
+    (``cfg.family``, GRU by default) + a linear classifier head over the
+    last layer's h.
 
-    Depth 1 keeps the seed's ``{"cell": ...}`` layout (checkpoint/example
-    compatibility); deeper stacks use ``{"cells": (layer0, layer1, ...)}``.
+    A depth-1 GRU keeps the seed's ``{"cell": ...}`` layout
+    (checkpoint/example compatibility); every other stack uses
+    ``{"cells": (layer0, layer1, ...)}``.
     """
+    from repro.core import cells
+    fam = cells.get_family(cells.cfg_family(cfg))
     head_in = cfg.resolved_layer_dims[-1]
     head = {
         "w": Spec((head_in, cfg.num_classes), ("hidden", None)),
         "b": Spec((cfg.num_classes,), (None,), init="zeros"),
     }
-    if cfg.resolved_num_layers == 1:
-        return {"cell": gru_cell_specs(cfg.input_dim, head_in), "head": head}
-    return {"cells": gru_stack_specs(cfg), "head": head}
+    stack = fam.stack_specs(cfg)
+    if fam.name == "gru" and len(stack) == 1:
+        return {"cell": stack[0], "head": head}
+    return {"cells": stack, "head": head}
 
 
 # ---------------------------------------------------------------------------
@@ -422,13 +428,14 @@ def gru_stack_reference(params: Sequence[dict], h0s: Sequence[jax.Array],
 
 
 def gru_classify(params: dict, xs: jax.Array, *, cfg: GRUConfig) -> jax.Array:
-    """Paper's jet-tagging forward pass: xs (B, T, X) -> logits (B, C).
-    Routed through the executor (``repro.core.runtime``)."""
-    from repro.core import runtime
-    B = xs.shape[0]
-    cells = stack_cell_params(params, cfg)
-    h0s = stack_h0(cfg, B, xs.dtype)
-    finals, _ = runtime.sequence(cells, h0s, xs, cfg=cfg)
+    """Paper's jet-tagging forward pass for the config's cell family:
+    xs (B, T, X) -> logits (B, C). Routed through the executor
+    (``repro.core.runtime``)."""
+    from repro.core import cells, runtime
+    fam = cells.get_family(cells.cfg_family(cfg))
+    state0 = fam.state0(cfg, xs.shape[0], fam.state_dtype or xs.dtype)
+    finals, _ = runtime.sequence(stack_cell_params(params, cfg), state0, xs,
+                                 cfg=cfg)
     return runtime.readout(finals[-1], params["head"])
 
 

@@ -249,6 +249,7 @@ def _slstm_family() -> cells_registry.CellFamily:
         stacked_views=stacked_views,
         supports_quant=False,          # no q8 views for the exp-gate path yet
         supports_placement=False,      # no shard_map backends registered
+        state_dtype="float32",
     )
 
 

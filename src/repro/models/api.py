@@ -17,7 +17,7 @@ import numpy as np
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core.params import Spec
 from repro.core import cells as cell_families
-from repro.models import gru_lm, hymba, llava, slstm_lm, transformer, whisper, xlstm
+from repro.models import gru_lm, hymba, llava, transformer, whisper, xlstm
 
 
 def _transformer_api():
@@ -81,6 +81,8 @@ def _hymba_api():
 
 
 def _gru_api():
+    # every cell family (gru, slstm): gru_lm takes what differs from the
+    # family's registration (repro.core.cells)
     return SimpleNamespace(
         specs=gru_lm.lm_specs,
         prepare_params=gru_lm.prepare_params,      # one-time serving prep
@@ -94,20 +96,6 @@ def _gru_api():
     )
 
 
-def _slstm_api():
-    return SimpleNamespace(
-        specs=slstm_lm.lm_specs,
-        prepare_params=slstm_lm.prepare_params,    # one-time serving prep
-        executable=slstm_lm.serve_executable,      # compiled-plan introspection
-        loss_fn=lambda p, cfg, batch, ctx: slstm_lm.loss_fn(p, cfg, batch, ctx=ctx),
-        forward=lambda p, cfg, batch, ctx: slstm_lm.forward(p, cfg, batch, ctx=ctx),
-        prefill=lambda p, cfg, batch, ctx: slstm_lm.prefill(p, cfg, batch, ctx=ctx),
-        decode_step=lambda p, cfg, cache, x, ctx: slstm_lm.decode_step(p, cfg, cache, x, ctx=ctx),
-        cache_specs=slstm_lm.cache_specs,
-        init_cache=slstm_lm.init_cache,
-    )
-
-
 _FAMS: Dict[str, Callable] = {
     "dense": _transformer_api,
     "moe": _transformer_api,
@@ -116,7 +104,7 @@ _FAMS: Dict[str, Callable] = {
     "ssm": _xlstm_api,
     "hybrid": _hymba_api,
     "gru": _gru_api,
-    "slstm": _slstm_api,
+    "slstm": _gru_api,
 }
 
 

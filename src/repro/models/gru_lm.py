@@ -1,17 +1,25 @@
-"""The paper's own model family (``gru-jet`` and deep stacks) behind the
-framework model API.
+"""The recurrent cell families (``gru-jet``, deep GRU stacks, ``slstm-jet``)
+behind the framework model API: one module serves every family registered
+in :mod:`repro.core.cells`.
 
-Forward/loss = the jet-tagging sequence classifier (GRU stack + linear
-head; the paper's validated configuration is one layer, H=20, X=5, 5
-classes). Serving = single-step recurrent decode through the whole stack,
-the paper's latency-measurement path; the cache carries one hidden state
-per layer.
+Forward/loss = the jet-tagging sequence classifier (recurrent stack +
+linear head; the paper's validated configuration is one GRU layer, H=20,
+X=5, 5 classes). Serving = single-step recurrent decode through the whole
+stack, the paper's latency-measurement path. What differs by family is
+taken from its :class:`~repro.core.cells.CellFamily`: the parameter specs
+(``stack_specs``), the initial state (``state0``: zeros, and the sLSTM
+stabilizer at ``M_INIT``; in ``state_dtype`` where the family fixes one)
+and the state's leaves per layer
+(``state_leaves``: the GRU's ``h``; the sLSTM's ``c, n, m, h``). The cache
+carries the family's flat state tuple under ``"h"``, layer-major, each
+leaf a ``(B, H)`` array; the readout hidden state is the last leaf.
 
-All GRU execution routes through the capability-dispatched executor
+All execution routes through the capability-dispatched executor
 (``repro.core.runtime``) via its two-stage compile/execute API:
 ``prefill``/``decode_step`` ask ``compile()`` for a memoized
-``GRUExecutable`` (fused Pallas stack, per-layer Pallas chain, XLA scan,
-or the shard_map programs when the ``ShardCtx`` carries a mesh — the ctx
+``GRUExecutable`` from the family's ``(family, backend)`` namespace
+(fused Pallas stack, per-layer Pallas chain, XLA scan, or — GRU only —
+the shard_map programs when the ``ShardCtx`` carries a mesh — the ctx
 mesh becomes the executable's ``Placement``, and mesh prefill resolves
 to ``pallas_sharded``, the fused shard kernels INSIDE the shard_map,
 unless pinned or calibrated otherwise), and ``serve_executable`` exposes
@@ -25,10 +33,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.core import cells, runtime
 from repro.core import gru as gru_core
-from repro.core import runtime
 from repro.core.params import Spec, init_params
 from repro.distributed.sharding import ShardCtx, constrain
+
+
+def _family(cfg: ModelConfig) -> cells.CellFamily:
+    return cells.get_family(cells.cfg_family(cfg.gru))
 
 
 def lm_specs(cfg: ModelConfig) -> dict:
@@ -94,18 +106,25 @@ def serve_executable(cfg: ModelConfig, *, batch: int, seq: int = None,
 
 
 def cache_specs(cfg: ModelConfig, batch: int, capacity: int = 0) -> dict:
-    """Recurrent cache: one hidden state PER LAYER of the stack."""
+    """Recurrent cache: the family's state leaves PER LAYER of the stack,
+    layer-major. Zeros are not every family's initial state (the sLSTM
+    stabilizer starts at ``M_INIT``): use :func:`init_cache` (or a
+    ``prefill``-produced cache), never ``init_params`` on these specs."""
+    leaves = _family(cfg).state_leaves
     return {
         "h": tuple(
             Spec((batch, h), ("batch", "act_gates"), init="zeros",
                  dtype="float32")
-            for h in cfg.gru.resolved_layer_dims),
+            for h in cfg.gru.resolved_layer_dims
+            for _ in range(leaves)),
         "pos": Spec((), (), init="zeros", dtype="int32"),
     }
 
 
 def init_cache(cfg: ModelConfig, batch: int, capacity: int = 0) -> dict:
-    return init_params(cache_specs(cfg, batch), jax.random.key(0))
+    cache = init_params(cache_specs(cfg, batch), jax.random.key(0))
+    cache["h"] = _family(cfg).state0(cfg.gru, batch, jnp.float32)
+    return cache
 
 
 def decode_step(params: dict, cfg: ModelConfig, cache: dict, x: jax.Array, *,
@@ -114,8 +133,8 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict, x: jax.Array, *,
     (class logits so far, cache).
 
     The executor dispatches: with ``cfg.gru.backend == "pallas"`` (uniform
-    layer sizes) the whole depth runs as ONE fused pallas_call — the
-    per-layer cache states are stacked device-side and fed straight to the
+    layer sizes) the whole depth runs as ONE fused pallas_call — every
+    layer's cache leaves are stacked device-side and fed straight to the
     kernel, no host round trips on the latency-critical path; hetero
     stacks run the per-layer Pallas chain. Params prepared by
     ``prepare_params`` carry pre-stacked (and, under a mesh, pre-placed)
@@ -130,21 +149,23 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict, x: jax.Array, *,
 
 def prefill(params: dict, cfg: ModelConfig, batch: dict, *,
             ctx: ShardCtx = ShardCtx()):
-    """Run the full sequence, return (logits, per-layer recurrent state).
+    """Run the full sequence, return (logits, flat recurrent state).
 
-    ``batch["mask"]`` (B, T) bool, optional: False timesteps freeze the
-    recurrence, so left-padded bucketed prompts (ServeEngine) yield the
-    same state as their unpadded originals — streamed through whichever
-    backend the executor picks (the fused Pallas kernels included; masked
-    bucketed prefill no longer falls back to the XLA scan)."""
+    ``batch["mask"]`` (B, T) bool, optional: False timesteps freeze every
+    state leaf (the sLSTM stabilizer included), so left-padded bucketed
+    prompts (ServeEngine) yield the same state as their unpadded
+    originals — streamed through whichever backend the executor picks
+    (the fused Pallas kernels included; masked bucketed prefill no longer
+    falls back to the XLA scan)."""
     xs = batch["features"]
     B = xs.shape[0]
     mask = batch.get("mask")
-    h0s = gru_core.stack_h0(cfg.gru, B, xs.dtype)
+    fam = _family(cfg)
+    state0 = fam.state0(cfg.gru, B, fam.state_dtype or xs.dtype)
     p = runtime.compile(cfg.gru, batch=B, seq=xs.shape[1],
                         mask=mask is not None, mode="prefill",
                         placement=_placement(ctx))
-    finals = p.prefill(params, h0s, xs, mask=mask)
+    finals = p.prefill(params, state0, xs, mask=mask)
     logits = runtime.readout(finals[-1], params["head"]).astype(jnp.float32)
     cache = {"h": tuple(h.astype(jnp.float32) for h in finals),
              "pos": jnp.array(xs.shape[1] - 1, jnp.int32)}

@@ -207,6 +207,12 @@ class ServeEngine:
         self.e2e_times: List[float] = []        # per request: submit -> finish
         self.device_waits = 0                   # waits on the device by the
                                                 # wave path: one per decode step
+        # device state arrays the wave cache holds, layers x the family's
+        # state leaves (each one more array every prefill returns and every
+        # scatter and decode takes and gives back); 0 off the wave path
+        self.state_arrays = (len(self.api.cache_specs(cfg, max_batch)["h"])
+                             if cell_families.is_cell_family(cfg.family)
+                             else 0)
 
     # -- jit caches ---------------------------------------------------------
 
@@ -741,7 +747,8 @@ class ServeEngine:
         engine that served nothing has no percentiles (``steps`` /
         ``requests`` / ``prefills`` say how much history backs each
         number). ``device_waits`` counts the wave path's waits on the
-        device: one per decode step."""
+        device: one per decode step; ``state_arrays`` is the number of
+        device arrays the wave cache's state is made of."""
         ts = self.step_times
         pf = self.prefill_times
         qw = self.queue_waits
@@ -786,4 +793,5 @@ class ServeEngine:
                 "prefill_mean_s": _mean(pf),
                 "prefill_p99_s": _pct(pf, 99),
                 "prefills": len(self.prefill_times),
-                "device_waits": self.device_waits}
+                "device_waits": self.device_waits,
+                "state_arrays": self.state_arrays}
