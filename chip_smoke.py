@@ -169,7 +169,7 @@ def cost_sources(engine) -> str:
     parts = [f"prefill[S={S}]="
              + exe(engine.cfg, batch=engine.max_batch, seq=S, masked=True,
                    mode="prefill", mesh=mesh).cost_source
-             for S in sorted(engine._prefill_jit)]
+             for S in sorted(engine._wave_jit)]
     parts.append("decode=" + exe(engine.cfg, batch=engine.max_batch,
                                  mode="decode", mesh=mesh).cost_source)
     return " ".join(parts)

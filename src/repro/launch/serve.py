@@ -153,7 +153,8 @@ def main(argv=None):
           f"p99={stats['p99_s']*1e3:.2f}ms ({stats['steps']} steps); "
           f"prefill mean={stats['prefill_mean_s']*1e3:.2f}ms "
           f"({stats['prefills']} prefills, "
-          f"{len(engine._prefill_jit)} bucket jits)")
+          f"{len(engine._wave_jit) + len(engine._prefill_jit)} "
+          f"bucket jits)")
     if is_cell:
         pf = sorted(set(engine.prefill_backends))
         steps = stats.get("decode_backend_steps", {})

@@ -25,11 +25,17 @@ Continuous batching (GRU waves): ``generate`` accepts MORE requests than
 (EOS or budget), the slots are retired mid-wave and queued requests are
 admitted into them. ALL requests admitted at one step share ONE bucketed
 prefill (batch padded to the slot shape, so no new compilation) whose
-rows are scattered into the freed slots of the live wave cache in one
-device-side update — when several slots free simultaneously the admit
-cost stays one prefill, not one per request. Finished streams therefore
-free capacity immediately instead of padding the wave to the slowest
-request.
+rows are scattered into the freed slots of the live wave cache — when
+several slots free simultaneously the admit cost stays one prefill, not
+one per request. Finished streams therefore free capacity immediately
+instead of padding the wave to the slowest request.
+
+One device program per step: an admitting step runs ``gru_wave`` (one
+jit per prefill bucket) — the prefill, the scatter of its rows into the
+freed slots through a fixed ``max_batch``-long slot index, the decode
+step and the argmax — and a step that admits nothing runs ``gru_decode``.
+Both take their host inputs as numpy arrays, and the step waits on the
+device once, for the classes.
 
 Autotuning: an attached :class:`repro.serve.autotune.AutoTuner` closes
 the loop from measured serving back into these knobs — wave size from
@@ -97,19 +103,21 @@ from repro.models import api as mapi
 
 # Host spans of one ``gru_wave_step``, written into the profiler's trace
 # (no cost beyond an enter/exit while no trace is being taken). The step
-# span is the parent; the others are its children, in this order, and an
-# admit-free step has no admit, prefill or scatter span (docs/serving.md,
+# span is the parent; the others are its children, in this order. An
+# admitting step dispatches its one program under ``engine.prefill``, a
+# step that admits nothing under ``engine.decode`` (docs/serving.md,
 # "Engine spans").
 SPAN_STEP = "engine.step"
 SPAN_ADMIT = "engine.admit"        # queue pop, slots, bucket, prompt staging
-SPAN_PREFILL = "engine.prefill"    # the prefill dispatch (``prefill_times``)
-SPAN_SCATTER = "engine.scatter"    # admitted rows into the wave cache
 SPAN_FEED = "engine.feed"          # next-feature staging, per slot
-SPAN_DECODE = "engine.decode"      # the decode dispatch
+SPAN_PREFILL = "engine.prefill"    # the wave dispatch (``prefill_times``)
+SPAN_DECODE = "engine.decode"      # the decode-only dispatch
 SPAN_READOUT = "engine.readout"    # the step's one wait: class download
 SPAN_RETIRE = "engine.retire"      # classes out, lanes retired, drain check
-STEP_SPANS = (SPAN_ADMIT, SPAN_PREFILL, SPAN_SCATTER, SPAN_FEED,
-              SPAN_DECODE, SPAN_READOUT, SPAN_RETIRE)
+STEP_SPANS = (SPAN_ADMIT, SPAN_FEED, SPAN_PREFILL, SPAN_READOUT,
+              SPAN_RETIRE)                        # an admitting step
+DECODE_SPANS = (SPAN_FEED, SPAN_DECODE, SPAN_READOUT,
+                SPAN_RETIRE)                      # a step that admits nothing
 
 
 @dataclass
@@ -194,7 +202,9 @@ class ServeEngine:
                                          # bucket jit (frozen at trace time)
         self._prefill_cold = set()       # post-retune buckets whose first
                                          # (compile) timing is excluded
-        self._scatter_jit = {}           # keyed by admit-batch size
+        self._wave_jit = {}              # keyed by prompt-length bucket
+        self._state0 = {}                # host initial wave cache, per
+                                         # slot count
         self._jit_gen = 0                # bumped per _invalidate_jits call
         self._wave: Optional[_GruWave] = None
         self.step_times: List[float] = []
@@ -207,58 +217,85 @@ class ServeEngine:
         self.e2e_times: List[float] = []        # per request: submit -> finish
         self.device_waits = 0                   # waits on the device by the
                                                 # wave path: one per decode step
+        self.wave_calls = 0                     # admitting steps served by
+                                                # the one ``gru_wave`` program
         # device state arrays the wave cache holds, layers x the family's
-        # state leaves (each one more array every prefill returns and every
-        # scatter and decode takes and gives back); 0 off the wave path
+        # state leaves (each one more array every program of a wave step
+        # takes and gives back); 0 off the wave path
         self.state_arrays = (len(self.api.cache_specs(cfg, max_batch)["h"])
                              if cell_families.is_cell_family(cfg.family)
                              else 0)
 
     # -- jit caches ---------------------------------------------------------
 
+    def _decode_classes(self, params, cache, x):
+        """One decode step and the argmax of its logits (int32, first index
+        on ties): the classes are the only result the host downloads."""
+        logits, cache = self.api.decode_step(params, self.cfg, cache, x,
+                                             self.ctx)
+        return jnp.argmax(logits, -1).astype(jnp.int32), cache
+
     def _get_decode(self, batch_shape: tuple):
         """Decode step jit, keyed by the new-input batch shape; returns
-        ``(classes, cache)``: the argmax of the step's logits (int32, first
-        index on ties) is computed inside the program, so the classes are
-        the only result the host downloads. The cache is donated, so an
-        unkeyed entry reused at a different batch shape would silently
-        retrace; the key makes the compile-once contract checkable (see
+        ``(classes, cache)``. The cache is donated, so an unkeyed entry
+        reused at a different batch shape would silently retrace; the key
+        makes the compile-once contract checkable (see
         test_serve_engine_decode_cache_keyed_by_batch)."""
         if batch_shape not in self._decode_jit:
             def gru_decode(params, cache, tok):
-                logits, cache = self.api.decode_step(params, self.cfg, cache,
-                                                     tok, self.ctx)
-                return jnp.argmax(logits, -1).astype(jnp.int32), cache
+                return self._decode_classes(params, cache, tok)
             self._decode_jit[batch_shape] = jax.jit(gru_decode,
                                                     donate_argnums=(1,))
         return self._decode_jit[batch_shape]
+
+    def _mark_cold(self, S: int) -> None:
+        """A bucket jit (re)created after a mid-serve retune: its first
+        call recompiles, and that compile is a tuning cost — excluded
+        from the percentiles exactly like the per-decode-jit rule
+        (_record_prefill). First-EVER bucket compiles (gen 0) stay
+        included: cold-start is part of the prefill story."""
+        if self._jit_gen > 0:
+            self._prefill_cold.add(S)
 
     def _get_prefill(self, S: int):
         if S not in self._prefill_jit:
             def gru_prefill(params, batch):
                 return self.api.prefill(params, self.cfg, batch, self.ctx)
             self._prefill_jit[S] = jax.jit(gru_prefill)
-            if self._jit_gen > 0:
-                # a jit (re)created after a mid-serve retune: its first
-                # call recompiles, and that compile is a tuning cost —
-                # excluded from the percentiles exactly like the
-                # per-decode-jit rule (_record_prefill). First-EVER bucket
-                # compiles (gen 0) stay included: cold-start is part of
-                # the prefill story.
-                self._prefill_cold.add(S)
+            self._mark_cold(S)
         return self._prefill_jit[S]
 
     def _get_scatter(self, k: int):
-        """Admit-k cache scatter: copy rows 0..k-1 of a freshly prefilled
-        cache into the ``k`` freed slots of the live wave cache
-        (device-side, one trace per admit-batch size k <= max_batch)."""
-        if k not in self._scatter_jit:
-            def gru_scatter(cache, fresh, slots_):
-                return {"h": tuple(h.at[slots_].set(f[:k]) for h, f in
-                                   zip(cache["h"], fresh["h"])),
-                        "pos": cache["pos"]}
-            self._scatter_jit[k] = jax.jit(gru_scatter)
-        return self._scatter_jit[k]
+        """The admit scatter that ``gru_wave`` traces: row ``i`` of the
+        freshly prefilled cache goes to slot ``slots_[i]`` of the live
+        wave cache, for the ``k`` rows of the slot index; an index of
+        ``max_batch`` (no slot) drops its row. Rows not named keep their
+        state, so a cancelled lane's stale row stays inert. A method, so
+        that ``bench/tools/faults.py`` can plant its scatter fault here."""
+        def gru_scatter(cache, fresh, slots_):
+            return {"h": tuple(h.at[slots_].set(f[:k], mode="drop")
+                               for h, f in zip(cache["h"], fresh["h"])),
+                    "pos": cache["pos"]}
+        return gru_scatter
+
+    def _get_wave(self, Sb: int):
+        """The one program of an admitting wave step, keyed by prefill
+        bucket ``Sb`` alone: the bucketed prefill of the staged slot
+        batch, its rows scattered into the live cache at a fixed
+        ``max_batch``-long slot index (one program for every admit size),
+        one decode step over the merged cache and the argmax. Returns
+        ``(classes, cache)``; the live cache is donated."""
+        if Sb not in self._wave_jit:
+            def gru_wave(params, cache, feats, mask, slots_, nxt):
+                _, fresh = self.api.prefill(
+                    params, self.cfg, {"features": feats, "mask": mask},
+                    self.ctx)
+                cache = self._get_scatter(self.max_batch)(cache, fresh,
+                                                          slots_)
+                return self._decode_classes(params, cache, nxt)
+            self._wave_jit[Sb] = jax.jit(gru_wave, donate_argnums=(1,))
+            self._mark_cold(Sb)
+        return self._wave_jit[Sb]
 
     # -- LM waves -----------------------------------------------------------
 
@@ -388,22 +425,42 @@ class ServeEngine:
             return
         self.prefill_times.append(dt)
 
-    def _gru_prefill(self, Sb: int, feats: np.ndarray, mask: np.ndarray):
-        """One bucketed prefill of a staged slot batch (bucket ``Sb``, from
-        ``_gru_prefill_batch``); returns cache. Nothing waits on the
-        device here: the host reads no prefill result, so the cache flows
-        on to the scatter and the decode, which the device runs in order.
-        ``prefill_times`` records what the host pays for the call: the
-        copies of features and mask and the dispatch."""
+    def _initial_cache(self) -> dict:
+        """The live cache a wave's first cohort is scattered into: the
+        family's initial state in every slot, ``pos`` 0 (so a wave cache's
+        ``pos`` counts the decode steps the wave has run). Built once per
+        slot count on the host; each wave gets its own device copy, which
+        its first step donates."""
+        Bs = self.max_batch
+        if Bs not in self._state0:
+            self._state0[Bs] = jax.tree.map(
+                np.asarray, self.api.init_cache(self.cfg, Bs))
+        return jax.device_put(self._state0[Bs])
+
+    def _gru_wave(self, w: _GruWave, Sb: int, feats: np.ndarray,
+                  mask: np.ndarray, slots: np.ndarray):
+        """Dispatch an admitting step's one program (bucket ``Sb``) on the
+        staged slot batch and the wave's next features; returns the
+        classes (not waited on) and the dispatch time. ``prefill_times``
+        records what the host pays for the call: the dispatch of numpy
+        arguments, no copies and no wait."""
         backend = self._prefill_backend_for(Sb)
         if backend is not None:          # record the executor's choice
             self.prefill_backends.append(backend)
-        prefill = self._get_prefill(Sb)
+        wave = self._get_wave(Sb)
+        cache = self._initial_cache() if w.cache is None else w.cache
+        if w.key not in self._decode_jit:
+            # a later step that admits nothing runs ``gru_decode``: compile
+            # it beside the first wave program, so that a warm-up which
+            # only admits leaves nothing to compile for such a step
+            self._get_decode(w.key).lower(self.params, cache,
+                                          w.nxt).compile()
         t0 = self.clock.now()
-        _, cache = prefill(self.params, {"features": jnp.asarray(feats),
-                                         "mask": jnp.asarray(mask)})
+        classes, w.cache = wave(self.params, cache, feats, mask, slots,
+                                w.nxt)
         self._record_prefill(Sb, self.clock.now() - t0)
-        return cache
+        self.wave_calls += 1
+        return classes, t0
 
     def _make_slot(self, r: Request) -> _Slot:
         X = self.cfg.gru.input_dim
@@ -483,7 +540,8 @@ class ServeEngine:
     def bucket_warm(self, prompt_len: int) -> bool:
         """Whether this engine has already compiled the prefill bucket a
         prompt of ``prompt_len`` lands in (router bucket-affinity)."""
-        return self._bucket_for(prompt_len) in self._prefill_jit
+        Sb = self._bucket_for(prompt_len)
+        return Sb in self._wave_jit or Sb in self._prefill_jit
 
     # -- autotune surface (repro.serve.autotune) ----------------------------
     #
@@ -506,7 +564,7 @@ class ServeEngine:
 
     def _invalidate_jits(self) -> None:
         """Drop every shape-dependent jit (prefill buckets, decode steps,
-        admit scatters) plus the frozen backend attributions, so the next
+        wave programs) plus the frozen backend attributions, so the next
         call re-traces against the CURRENT wave size and cost epoch.
         Only wave-boundary retunes call this. The warm/cold markers reset
         with the jits: each re-created jit's first (compile) step is
@@ -514,7 +572,7 @@ class ServeEngine:
         _record_prefill)."""
         self._prefill_jit.clear()
         self._decode_jit.clear()
-        self._scatter_jit.clear()
+        self._wave_jit.clear()
         self._decode_plan_backends.clear()
         self._prefill_plan_backends.clear()
         self._decode_warm.clear()
@@ -523,8 +581,8 @@ class ServeEngine:
 
     def apply_wave_size(self, n: int) -> None:
         """Resize the decode slot count (tuner decision). Every jit here
-        is batch-shaped — prefill pads to ``max_batch`` rows, decode and
-        scatter trace the slot axis — so the caches are invalidated; a
+        is batch-shaped — prefill pads to ``max_batch`` rows, the wave and
+        decode programs trace the slot axis — so the caches are invalidated; a
         drained wave object is dropped so the next enqueue builds slots
         at the new size. Callable only between waves (enforced by
         _maybe_retune being the sole caller path)."""
@@ -600,19 +658,22 @@ class ServeEngine:
 
     def gru_wave_step(self) -> List[Request]:
         """Advance the wave ONE decode step: admit queued requests into
-        every empty slot (ALL admits share ONE bucketed prefill + one
-        scatter), run one fused decode step over the fixed slots, retire
+        every empty slot, run one decode step over the fixed slots, retire
         finished lanes. Returns the requests that finished this step.
 
-        The prefill, the scatter and the decode are dispatched back to
-        back; the step waits on the device once, for the classes
-        (``device_waits``)."""
+        A step that admits runs ONE program (``gru_wave``: the bucketed
+        prefill of every admit, their scatter into the freed slots, the
+        decode and the argmax); a step that admits nothing runs the
+        decode-only program. Either way the step waits on the device once,
+        for the classes (``device_waits``)."""
         with TraceAnnotation(SPAN_STEP):
             w = self._wave
             if w is None:
                 return []
             X = self.cfg.gru.input_dim
+            Bs = self.max_batch
             empty = [j for j, s in enumerate(w.slots) if s is None]
+            Sb = None
             if empty and w.pending:
                 with TraceAnnotation(SPAN_ADMIT):
                     k = min(len(empty), len(w.pending))
@@ -628,17 +689,10 @@ class ServeEngine:
                                for s in admits]
                     Sb = self._bucket_for(max(p.shape[0] for p in prompts))
                     feats, mask = self._gru_prefill_batch(prompts, Sb)
-                with TraceAnnotation(SPAN_PREFILL):
-                    fresh = self._gru_prefill(Sb, feats, mask)
-                with TraceAnnotation(SPAN_SCATTER):
-                    if w.cache is None:
-                        # first cohort: the prefilled cache IS the wave
-                        # cache (row i belongs to slot i; surplus rows are
-                        # fully masked)
-                        w.cache = fresh
-                    else:
-                        w.cache = self._get_scatter(k)(
-                            w.cache, fresh, jnp.asarray(empty[:k], jnp.int32))
+                    # staged row i goes to slot empty[i]; the rest of the
+                    # fixed-length index names no slot and is dropped
+                    slots = np.full(Bs, Bs, np.int32)
+                    slots[:k] = empty[:k]
                     for j, s in zip(empty[:k], admits):
                         w.slots[j] = s
             if not any(s is not None for s in w.slots):
@@ -655,21 +709,26 @@ class ServeEngine:
             # (_decode_backend_for): the jitted step embeds whichever
             # backend the executor resolved when it first traced, and later
             # cost-model epoch bumps do NOT retrace it — so a fresh
-            # compile() mid-wave could only MIS-attribute. Steps are
-            # recorded under the key they ran with; if admits ever change
-            # the decode key (live-batch resizing), the new key resolves its
-            # own backend on first use. A step's time runs from the decode
-            # dispatch to the classes in hand, so on an admitting step it
-            # also holds the device time of this step's prefill and scatter.
-            with TraceAnnotation(SPAN_DECODE):
-                decode = self._get_decode(w.key)
-                t0 = self.clock.now()
-                classes, w.cache = decode(self.params, w.cache,
-                                          jnp.asarray(w.nxt))
+            # compile() mid-wave could only MIS-attribute. A step's time
+            # runs from its program's dispatch to the classes in hand, and
+            # is recorded under the key of the program that ran (each
+            # program's first, compiling step is excluded): ``w.key`` for
+            # the decode-only program, ``w.key + (Sb,)`` for the wave
+            # program of bucket ``Sb``.
+            if Sb is None:
+                with TraceAnnotation(SPAN_DECODE):
+                    decode = self._get_decode(w.key)
+                    t0 = self.clock.now()
+                    classes, w.cache = decode(self.params, w.cache, w.nxt)
+                key = w.key
+            else:
+                with TraceAnnotation(SPAN_PREFILL):
+                    classes, t0 = self._gru_wave(w, Sb, feats, mask, slots)
+                key = w.key + (Sb,)
             with TraceAnnotation(SPAN_READOUT):
                 cls = np.asarray(classes)
                 self.device_waits += 1
-                self._record_step(w.key, self.clock.now() - t0,
+                self._record_step(key, self.clock.now() - t0,
                                   self._decode_backend_for(w.key))
             with TraceAnnotation(SPAN_RETIRE):
                 finished = []
@@ -715,10 +774,11 @@ class ServeEngine:
 
     def _record_step(self, key: tuple, dt: float,
                      backend: Optional[str] = None) -> None:
-        """Record one decode-step latency, excluding each decode jit's
+        """Record one decode-step latency, excluding each step program's
         FIRST call (its compile) so the tail percentiles reflect steady
-        state, not compilation — per key, since every batch shape compiles
-        separately. ``backend`` attributes the step to the executor
+        state, not compilation — per key, since every batch shape (and
+        every wave program's bucket) compiles separately; ``key[0]`` is
+        the step's batch. ``backend`` attributes the step to the executor
         backend that actually ran it (``decode_backends`` stays aligned
         with ``step_times``)."""
         if key in self._decode_warm:
@@ -747,8 +807,9 @@ class ServeEngine:
         engine that served nothing has no percentiles (``steps`` /
         ``requests`` / ``prefills`` say how much history backs each
         number). ``device_waits`` counts the wave path's waits on the
-        device: one per decode step; ``state_arrays`` is the number of
-        device arrays the wave cache's state is made of."""
+        device: one per decode step; ``wave_calls`` the admitting steps
+        served by the one ``gru_wave`` program; ``state_arrays`` is the
+        number of device arrays the wave cache's state is made of."""
         ts = self.step_times
         pf = self.prefill_times
         qw = self.queue_waits
@@ -794,4 +855,5 @@ class ServeEngine:
                 "prefill_p99_s": _pct(pf, 99),
                 "prefills": len(self.prefill_times),
                 "device_waits": self.device_waits,
+                "wave_calls": self.wave_calls,
                 "state_arrays": self.state_arrays}

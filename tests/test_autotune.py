@@ -307,20 +307,23 @@ def test_post_retune_prefill_jit_first_call_excluded():
 
 def test_post_retune_decode_jit_first_step_excluded_again():
     """After an invalidating retune (e.g. a recalibration that changed a
-    resolved backend), the re-created decode jit's first step is a
-    compile again and must be excluded — same per-jit rule as its first
-    life, even though the key is unchanged."""
+    resolved backend), the re-created step programs' first steps are
+    compiles again and must be excluded — same per-jit rule as their
+    first life, even though the keys are unchanged. A wave's first step
+    admits (the ``gru_wave`` program) and the next decode-only step is
+    the ``gru_decode`` program's first: one compile step each."""
     cfg, params = _setup()
     engine = ServeEngine(cfg, params, ShardCtx(), max_batch=2,
                          clock=ManualClock())
     engine.generate(_requests(cfg, [3, 3], max_new=3))
     n0 = len(engine.step_times)
-    assert n0 == 3 - 1                       # first step excluded per key
+    assert n0 == 3 - 2                       # first step excluded per key
     engine._invalidate_jits()                # what a backend-change does
     assert engine._decode_jit == {} and engine._decode_warm == set()
+    assert engine._wave_jit == {}
     engine.generate(_requests(cfg, [3, 3], max_new=3))
-    # the re-created jit recorded one step fewer than it ran
-    assert len(engine.step_times) == n0 + 3 - 1
+    # the re-created jits recorded one step fewer each than they ran
+    assert len(engine.step_times) == n0 + 3 - 2
     # prefill side of the same invalidation: bucket 8's jit was dropped
     # too, so its post-retune re-compile is excluded...
     assert len(engine.prefill_times) == 1
@@ -388,7 +391,8 @@ def test_autotuned_engine_acceptance_skewed_workload():
             assert "from" in d and "to" in d and d["t"] >= 0.0
         # no silent retraces: every live jit traced exactly one shape
         for jit_fn in (list(engine._decode_jit.values())
-                       + list(engine._prefill_jit.values())):
+                       + list(engine._prefill_jit.values())
+                       + list(engine._wave_jit.values())):
             cache_size = getattr(jit_fn, "_cache_size", None)
             if cache_size is not None:
                 assert cache_size() == 1

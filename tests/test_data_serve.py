@@ -111,8 +111,9 @@ def test_serve_engine_gru_matches_model_api():
 
 def test_serve_engine_no_retrace_same_bucket():
     """Two GRU waves with DIFFERENT prompt lengths in the same power-of-two
-    bucket share one prefill jit entry and trace it exactly once; the
-    decode step compiles once for the engine lifetime (fixed slots)."""
+    bucket share one wave-program entry (prefill bucket) and trace it
+    exactly once; the decode step compiles once for the engine lifetime
+    (fixed slots)."""
     cfg = get_smoke_config("gru-jet-deep")
     A = mapi.get_api(cfg)
     params = init_params(A.specs(cfg), jax.random.key(0), cfg.param_dtype)
@@ -124,19 +125,19 @@ def test_serve_engine_no_retrace_same_bucket():
                         max_new_tokens=2) for _ in range(2)]
 
     engine.generate(wave(5))                     # warmup: bucket 8
-    n_prefill = len(engine._prefill_jit)
+    n_prefill = len(engine._wave_jit)
     n_decode = len(engine._decode_jit)
-    traces = {k: f._cache_size() for k, f in engine._prefill_jit.items()}
+    traces = {k: f._cache_size() for k, f in engine._wave_jit.items()}
     engine.generate(wave(7))                     # different S, same bucket
-    assert len(engine._prefill_jit) == n_prefill == 1
+    assert len(engine._wave_jit) == n_prefill == 1
     assert len(engine._decode_jit) == n_decode == 1
-    for k, f in engine._prefill_jit.items():
+    for k, f in engine._wave_jit.items():
         assert f._cache_size() == traces[k] == 1, (k, f._cache_size())
     for f in engine._decode_jit.values():
         assert f._cache_size() == 1
     # a longer prompt opens exactly one NEW bucket
     engine.generate(wave(11))                    # bucket 16
-    assert len(engine._prefill_jit) == 2
+    assert len(engine._wave_jit) == 2
     assert len(engine._decode_jit) == 1
 
 
@@ -188,8 +189,8 @@ def test_serve_engine_gru_continuous_batching():
     # SAME bucket jit (prompts 3..6 all bucket to 8)
     stats = engine.latency_stats()
     assert stats["prefills"] == 3
-    assert len(engine._prefill_jit) == 1
-    for f in engine._prefill_jit.values():
+    assert len(engine._wave_jit) == 1
+    for f in engine._wave_jit.values():
         assert f._cache_size() == 1
     # mid-wave admission really overlapped: total decode steps is less than
     # a serial 2-slot schedule would need (bounded by the longest lane sum)

@@ -1,6 +1,7 @@
-"""One wait on the device per wave step: ``gru_wave_step`` dispatches the
-prefill, the scatter and the decode back to back and downloads only the
-classes, which the decode program computes itself (CPU, smoke sizes).
+"""One wait on the device per wave step: ``gru_wave_step`` dispatches one
+program (an admitting step's ``gru_wave``: prefill, scatter, decode; else
+``gru_decode``) and downloads only the classes, which that program
+computes itself (CPU, smoke sizes).
 
 Each wave has more requests than slots, ragged prompts over two buckets,
 streamed, free-running and stream-exhausted lanes, and requests enqueued
@@ -148,10 +149,12 @@ def test_classes_and_states_match_the_model_api_step_by_step(served):
 
 
 def test_records_keep_their_lengths(served):
-    """One ``step_times`` record a decoding step (the decode program's
-    first, compiling step excluded) and one ``prefill_times`` record an
+    """One ``step_times`` record a decoding step (each step program's
+    first, compiling step excluded: the wave program of buckets 8 and 16
+    and the decode-only program) and one ``prefill_times`` record an
     admitting step (every bucket compiled in this wave included)."""
     _, _, eng, reqs, _, (steps, admits), _ = served
-    assert len(eng.step_times) == len(eng.decode_backends) == steps - 1
+    assert len(eng._wave_jit) == 2 and len(eng._decode_jit) == 1
+    assert len(eng.step_times) == len(eng.decode_backends) == steps - 3
     assert len(eng.prefill_times) == len(eng.prefill_backends) == admits
     assert len(eng.queue_waits) == len(reqs)

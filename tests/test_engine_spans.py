@@ -14,10 +14,11 @@ from repro.core.params import init_params
 from repro.distributed.sharding import ShardCtx
 from repro.models import api as mapi
 from repro.serve import engine as engine_mod
-from repro.serve.engine import (SPAN_DECODE, SPAN_READOUT, SPAN_STEP,
-                                STEP_SPANS, Request, ServeEngine)
+from repro.serve.engine import (DECODE_SPANS, SPAN_DECODE, SPAN_PREFILL,
+                                SPAN_READOUT, SPAN_STEP, STEP_SPANS, Request,
+                                ServeEngine)
 
-ADMIT_FREE = STEP_SPANS[3:]         # feed, decode, readout, retire
+ADMIT_FREE = DECODE_SPANS           # feed, decode, readout, retire
 BUDGETS = [2, 5, 3, 4, 1, 2, 3]
 RECORDS = ("step_times", "prefill_times", "queue_waits", "e2e_times",
            "decode_backends", "prefill_backends")
@@ -131,14 +132,17 @@ def test_phases_cover_the_step(runs):
 @pytest.mark.parametrize("span,record", [("engine.decode", "step_times"),
                                          ("engine.prefill", "prefill_times")])
 def test_call_spans_agree_with_the_engine_records(runs, span, record):
-    """A ``prefill_times`` record is its ``engine.prefill`` span. A
-    ``step_times`` record runs from the decode dispatch to the classes in
-    hand, so it is the step's ``engine.decode`` and ``engine.readout``."""
+    """A ``prefill_times`` record is its admitting step's
+    ``engine.prefill`` span, the dispatch of the step's one program. A
+    ``step_times`` record runs from the step's dispatch to the classes in
+    hand, so it is the step's ``engine.decode`` (``engine.prefill`` on an
+    admitting step) and ``engine.readout``."""
     r = runs("traced")
-    parts = (span, SPAN_READOUT) if span == SPAN_DECODE else (span,)
+    parts = ((SPAN_DECODE, SPAN_PREFILL, SPAN_READOUT)
+             if span == SPAN_DECODE else (span,))
     got = [sum(e - s for n, s, e in children if n in parts) * 1e-9
            for _, children in _steps(r["spans"])
-           if any(n == span for n, _, _ in children)]
+           if any(n in parts for n, _, _ in children)]
     want = r["records"][record]
     calls = (len(r["admitted"]) if span == "engine.decode"
              else sum(r["admitted"]))
